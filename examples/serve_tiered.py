@@ -3,19 +3,16 @@ requests — prints the paper-style serving metrics.
 
   PYTHONPATH=src python examples/serve_tiered.py
 """
-import sys
-
 from repro.launch import serve as serve_launcher
 
 
 def main() -> None:
     for tiering in ("baseline", "skybyte"):
-        sys.argv = [
-            "serve", "--arch", "qwen3-1.7b", "--requests", "4",
+        serve_launcher.main([
+            "--arch", "qwen3-1.7b", "--requests", "4",
             "--prompt-len", "24", "--new-tokens", "16",
             "--tiering", tiering,
-        ]
-        serve_launcher.main()
+        ])
 
 
 if __name__ == "__main__":

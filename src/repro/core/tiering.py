@@ -17,6 +17,10 @@ The paper's memory system, re-expressed for an LLM serving engine:
 All device state is a flat dict of fixed-shape arrays (jit/pjit friendly);
 policy (promotion targets, flush targets, scheduling) is host-side, exactly
 as the paper splits FTL policy (firmware) from the data path (hardware).
+
+The kernels' path is chosen from the platform in one place,
+``kernel_mode``: compiled Pallas kernels on a TPU, the jnp reference
+everywhere else.
 """
 from __future__ import annotations
 
@@ -27,7 +31,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.kernels.kv_log_append.ref import kv_log_append_ref
 from repro.kernels.log_compact.ops import log_compact
 from repro.kernels.paged_attention.ops import paged_decode_attention
 from repro.models.api import ModelSpec
@@ -76,6 +79,13 @@ def init_state(
         # positions >= compacted live in the write log (disjointness)
         "compacted": jnp.zeros((c.max_requests,), jnp.int32),
     }
+
+
+def kernel_mode() -> str:
+    """How the served path runs its kernels: "compiled" Pallas kernels on a
+    TPU, the jnp "reference" on any other backend. Tests that need the
+    kernel bodies on the CPU patch this to return "interpret"."""
+    return "compiled" if jax.default_backend() == "tpu" else "reference"
 
 
 def host_slot(kv_cfg: TieredKVConfig, req: int, logical: int) -> int:
@@ -128,18 +138,19 @@ def write_prefill_pages(kv_cfg: TieredKVConfig, state, req: int, k, v):
     return state
 
 
-def build_paged_decode_step(
-    spec: ModelSpec, kv_cfg: TieredKVConfig, *, use_pallas: bool = False
-):
+def build_paged_decode_step(spec: ModelSpec, kv_cfg: TieredKVConfig):
     """Decode step over the tiered KV state for GQA decoder families
     (dense/moe/vlm). Returns step(params, state, tokens, req_ids) ->
-    (next_tokens, new_state).
+    (next_tokens, updates), where ``updates`` holds only the state entries
+    the step writes (the log, its metadata and the lengths); the page pools
+    are read, never returned, so a step copies none of them.
 
     The current token's K/V is appended to the write log (token-granular,
     no page read-modify-write — the paper's write path) and the attention
     reads pages + log in parallel (the paper's read path).
     """
     cfg = spec.cfg
+    mode = kernel_mode()
 
     def step(params, state, tokens, req_ids):
         B = tokens.shape[0]
@@ -175,8 +186,7 @@ def build_paged_decode_step(
             o = paged_decode_attention(
                 q[:, 0], hbm_k_l, hbm_v_l, page_table, lengths + 1,
                 log_k_l, log_v_l, log_meta,
-                page_lengths=compacted, req_ids=req_ids,
-                use_pallas=use_pallas,
+                page_lengths=compacted, req_ids=req_ids, mode=mode,
             )
             x2 = x + jnp.einsum("bh,hd->bd", o.reshape(B, -1), p_l["wo"])[:, None]
             h2 = rmsnorm(x2, p_l["mlp_norm"], cfg.norm_eps)
@@ -191,15 +201,16 @@ def build_paged_decode_step(
         logits = unembed(cfg, params, x)[:, 0]
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
 
-        new_state = dict(state)
-        new_state["log_k"] = log_k
-        new_state["log_v"] = log_v
-        new_state["log_meta"] = log_meta
-        new_state["log_tail"] = tail + B
-        new_state["lengths"] = state["lengths"].at[safe_req].add(
-            (req_ids >= 0).astype(jnp.int32)
-        )
-        return next_tok, new_state
+        updates = {
+            "log_k": log_k,
+            "log_v": log_v,
+            "log_meta": log_meta,
+            "log_tail": tail + B,
+            "lengths": state["lengths"].at[safe_req].add(
+                (req_ids >= 0).astype(jnp.int32)
+            ),
+        }
+        return next_tok, updates
 
     return step
 
@@ -211,15 +222,16 @@ def compact_log(
 
     flush_hbm / flush_host: (F, 3) int32 (request, logical_page, pool_slot)
     built by the engine from log_meta (unique dirty pages — the paper's
-    first-level hash-table scan)."""
+    first-level hash-table scan); rows with request -1 are padding."""
+    mode = kernel_mode()
     state = dict(state)
     state["hbm_k"], state["hbm_v"] = log_compact(
         state["hbm_k"], state["hbm_v"], state["log_k"], state["log_v"],
-        state["log_meta"], flush_hbm, use_pallas=False,
+        state["log_meta"], flush_hbm, mode=mode,
     )
     state["host_k"], state["host_v"] = log_compact(
         state["host_k"], state["host_v"], state["log_k"], state["log_v"],
-        state["log_meta"], flush_host, use_pallas=False,
+        state["log_meta"], flush_host, mode=mode,
     )
     state["log_meta"] = -jnp.ones_like(state["log_meta"])
     state["log_tail"] = jnp.zeros((), jnp.int32)

@@ -2,8 +2,10 @@
 
 Each kernel package has:
   kernel.py — pl.pallas_call + BlockSpec TPU implementation
-  ops.py    — jitted dispatch wrapper (``use_pallas`` flag; interpret=True
-              executes the kernel body on CPU for validation)
+  ops.py    — dispatch wrapper. The served kernels (paged_attention,
+              log_compact) take a ``mode``: "compiled" (TPU), "interpret"
+              (the kernel body through the Pallas interpreter, for CPU
+              tests) or "reference" (the jnp oracle)
   ref.py    — pure-jnp oracle
 
 Kernels:
@@ -15,3 +17,12 @@ Kernels:
                     (SIII-B log compaction)
   flash_attention — tiled causal attention for prefill (MXU-aligned)
 """
+
+MODES = ("compiled", "interpret", "reference")
+
+
+def pallas_interpret(mode: str) -> bool:
+    """The ``interpret`` argument of a Pallas kernel run in ``mode``."""
+    if mode not in MODES:
+        raise ValueError(f"unknown kernel mode {mode!r}; known: {MODES}")
+    return mode == "interpret"

@@ -68,7 +68,7 @@ def log_compact_pallas(
     log_meta: jax.Array,  # (S, 2)
     flush_targets: jax.Array,  # (F, 3)
     *,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
     L, P, page, KV, hd = k_pages.shape
     S = log_k.shape[1]
@@ -106,14 +106,11 @@ def log_compact_pallas(
         interpret=interpret,
     )(flush_targets, log_meta, log_k, log_v, k_pages, v_pages)
 
-    # scatter merged pages into the pool (slot -1 -> discarded via clamp+where)
+    # scatter merged pages into the pool; padding rows (request or slot -1)
+    # aim past the pool and are dropped, so they never race a real row
     slots = flush_targets[:, 2]
     valid = (flush_targets[:, 0] >= 0) & (slots >= 0)
-    safe = jnp.maximum(slots, 0)
-    cur_k = k_pages[:, safe]  # (L, F, page, KV, hd)
-    cur_v = v_pages[:, safe]
-    sel_k = jnp.where(valid[None, :, None, None, None], merged_k, cur_k)
-    sel_v = jnp.where(valid[None, :, None, None, None], merged_v, cur_v)
-    k_pages = k_pages.at[:, safe].set(sel_k)
-    v_pages = v_pages.at[:, safe].set(sel_v)
+    dest = jnp.where(valid, slots, P)
+    k_pages = k_pages.at[:, dest].set(merged_k, mode="drop")
+    v_pages = v_pages.at[:, dest].set(merged_v, mode="drop")
     return k_pages, v_pages

@@ -14,6 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_interpret
 from repro.kernels.paged_attention.kernel import paged_decode_attention_pallas
 from repro.kernels.paged_attention.ref import paged_decode_attention_ref
 
@@ -54,10 +55,13 @@ def paged_decode_attention(
     page_lengths: Optional[jax.Array] = None,
     req_ids: Optional[jax.Array] = None,
     *,
-    use_pallas: bool = True,
-    interpret: bool = True,
+    mode: str,
 ) -> jax.Array:
     """(B, H, hd) attention output over pages (+ log).
+
+    ``mode``: "compiled" runs the Pallas kernel compiled for the TPU,
+    "interpret" runs it through the Pallas interpreter, "reference" runs
+    the jnp oracle.
 
     ``page_lengths`` (default = lengths): per-request compaction watermark —
     page entries are valid only below it; positions at/above it live in the
@@ -70,7 +74,7 @@ def paged_decode_attention(
         page_lengths = lengths
     if req_ids is None:
         req_ids = jnp.arange(q.shape[0], dtype=jnp.int32)
-    if not use_pallas:
+    if mode == "reference":
         return paged_decode_attention_ref(
             q, k_pages, v_pages, page_table, lengths, log_k, log_v, log_meta,
             page_lengths=page_lengths, req_ids=req_ids,
@@ -79,7 +83,8 @@ def paged_decode_attention(
     KV = k_pages.shape[2]
     g = H // KV
     out_p, m_p, l_p = paged_decode_attention_pallas(
-        q, k_pages, v_pages, page_table, page_lengths, interpret=interpret
+        q, k_pages, v_pages, page_table, page_lengths,
+        interpret=pallas_interpret(mode),
     )
     if log_k is None:
         return out_p

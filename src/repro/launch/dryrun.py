@@ -294,7 +294,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, outdir: Path, force=Fal
         return rec
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             rec = lower_cell(arch, shape_name, mesh)
         rec["ok"] = True
     except Exception as e:

@@ -13,15 +13,25 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape, axes) -> jax.sharding.Mesh:
+    """A mesh with Auto axes. The model code states layouts as sharding hints
+    and leaves propagation to the compiler; ``jax.make_mesh`` defaults to
+    Explicit axes, which type-check every op's sharding instead. Enter it
+    with ``jax.set_mesh`` so the hints see its axis names."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Degenerate 1x1 mesh for CPU smoke tests through the same code path."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def dp_size(mesh: jax.sharding.Mesh) -> int:

@@ -62,8 +62,11 @@ def build_train_step(
         params = state["params"]
 
         def split(t):
+            # microbatch i takes rows i, i + accum, ...: the batch axis keeps
+            # its data sharding and the scanned (leading) axis is unsharded
             B = t.shape[0]
-            return t.reshape(accum_steps, B // accum_steps, *t.shape[1:])
+            t = t.reshape(B // accum_steps, accum_steps, *t.shape[1:])
+            return jnp.swapaxes(t, 0, 1)
 
         micro = {k: split(v) for k, v in batch.items()}
 

@@ -24,6 +24,7 @@ import numpy as np
 from repro.checkpoint import Checkpointer
 from repro.configs import ARCH_IDS, OptimConfig, get_config, get_reduced
 from repro.data.pipeline import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import build_train_step, make_train_state
 from repro.models.api import ModelSpec
 
@@ -46,6 +47,7 @@ def main() -> None:
                     help="simulate a crash at this step (recovery drill)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     spec = ModelSpec(cfg)

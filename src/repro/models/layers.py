@@ -26,22 +26,8 @@ PS = jax.sharding.PartitionSpec
 
 
 def _current_mesh_axes() -> Tuple[str, ...]:
-    """Axis names of whatever mesh context is active (new or legacy), or ()."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.shape_tuple:
-            return tuple(n for n, _ in m.shape_tuple)
-    except Exception:
-        pass
-    try:
-        from jax.interpreters import pxla
-
-        m = pxla.thread_resources.env.physical_mesh
-        if not m.empty:
-            return tuple(m.axis_names)
-    except Exception:
-        pass
-    return ()
+    """Axis names of the mesh entered with ``jax.set_mesh``, or ()."""
+    return tuple(n for n, _ in jax.sharding.get_abstract_mesh().shape_tuple)
 
 
 import os as _os

@@ -21,10 +21,17 @@ Per decode step:
 
 Stats mirror the simulator's so the TPU runtime can be judged with the
 paper's own metrics (coalescing ratio, switch count, fetch traffic).
+
+The decode step and the compaction are compiled ahead of time when the
+engine is built (``compile_seconds`` records the set-up time of each, and
+``step_fn``/``compact_fn`` are the compiled programs); their kernel path
+follows the platform (``tiering.kernel_mode``).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import time
 from typing import Dict, List, Optional
 
 import jax
@@ -66,22 +73,43 @@ class ServeStats:
 
 
 class TieredEngine:
-    def __init__(self, spec: ModelSpec, params, kv_cfg: TieredKVConfig,
-                 use_pallas: bool = False):
+    def __init__(self, spec: ModelSpec, params, kv_cfg: TieredKVConfig):
         self.spec = spec
         self.cfg = spec.cfg
         self.kv = kv_cfg
         self.params = params
         self.state = tiering.init_state(kv_cfg, spec.cfg, dtype=jnp.bfloat16)
-        self.step_fn = jax.jit(
-            tiering.build_paged_decode_step(spec, kv_cfg, use_pallas=use_pallas)
+        B = kv_cfg.batch
+        i32 = jnp.int32
+        # flush lists are padded to log_slots rows (each log entry dirties at
+        # most one page), so one compaction program serves every flush
+        flush = jax.ShapeDtypeStruct((kv_cfg.log_slots, 3), i32)
+        self.compile_seconds: Dict[str, float] = {}
+        self.step_fn = self._compile(
+            "decode", jax.jit(tiering.build_paged_decode_step(spec, kv_cfg)),
+            params, self.state,
+            jax.ShapeDtypeStruct((B, 1), i32), jax.ShapeDtypeStruct((B,), i32),
         )
+        # the pools are donated: compaction rewrites them in place
+        self.compact_fn = self._compile(
+            "compact",
+            jax.jit(functools.partial(tiering.compact_log, kv_cfg),
+                    donate_argnums=0),
+            self.state, flush, flush,
+        )
+        self.prefill_fn = jax.jit(spec.prefill)  # compiles once per length
         self.requests: Dict[int, Request] = {}
         # host-side metadata
         self.hbm_owner: List[Optional[tuple]] = [None] * kv_cfg.n_hbm_pages
         self.lru: np.ndarray = np.zeros(kv_cfg.n_hbm_pages, np.int64)
         self.stats = ServeStats()
         self._clock = 0
+
+    def _compile(self, name: str, fn, *args):
+        t0 = time.perf_counter()
+        compiled = fn.lower(*args).compile()
+        self.compile_seconds[name] = time.perf_counter() - t0
+        return compiled
 
     # ---- admission ----
     def add_request(self, req: Request) -> None:
@@ -95,7 +123,7 @@ class TieredEngine:
         rid = req.rid
         self.requests[rid] = req
         prompt = jnp.asarray(req.prompt, jnp.int32)[None]
-        logits, cache = self.spec.prefill(self.params, prompt)
+        logits, cache = self.prefill_fn(self.params, prompt)
         k = cache["k"][:, 0]  # (L, S, KV, hd)
         v = cache["v"][:, 0]
         # initial placement: prompt KV lands in the HOST tier (the paper's
@@ -125,7 +153,9 @@ class TieredEngine:
                 return s
         # LRU eviction among non-protected pages (clean by construction:
         # the log owns all un-flushed writes — the paper's key invariant)
-        order = np.argsort(self.lru)
+        # stable: equal stamps evict in slot order on every host (numpy's
+        # default sort breaks ties differently with the CPU's SIMD width)
+        order = np.argsort(self.lru, kind="stable")
         for s in order:
             if self.hbm_owner[s] is not None and self.hbm_owner[s] not in protect:
                 rid, logical = self.hbm_owner[s]
@@ -171,11 +201,16 @@ class TieredEngine:
             flush_host.append([rid, logical, host_slot(self.kv, rid, logical)])
             self.stats.flushed_pages += 1
             self.stats.flushed_tokens += ntok
-        pad = [[-1, 0, -1]]
-        fh = jnp.asarray((flush_hbm or pad), jnp.int32)
-        fo = jnp.asarray((flush_host or pad), jnp.int32)
-        self.state = tiering.compact_log(self.kv, self.state, fh, fo)
+        self.state = self.compact_fn(
+            self.state, self._flush_rows(flush_hbm), self._flush_rows(flush_host)
+        )
         self.stats.compactions += 1
+
+    def _flush_rows(self, rows: List[List[int]]) -> jax.Array:
+        out = np.full((self.kv.log_slots, 3), -1, np.int32)
+        out[:, 1] = 0
+        out[: len(rows)] = np.asarray(rows, np.int32).reshape(-1, 3)
+        return jnp.asarray(out)
 
     # ---- one engine step ----
     def step(self) -> None:
@@ -223,9 +258,10 @@ class TieredEngine:
             req_ids[i] = r.rid
             last = r.out[-1] if r.out else r.prompt[-1]
             tokens[i, 0] = last
-        next_tok, self.state = self.step_fn(
+        next_tok, updates = self.step_fn(
             self.params, self.state, jnp.asarray(tokens), jnp.asarray(req_ids)
         )
+        self.state.update(updates)
         next_np = np.asarray(next_tok)
         for i, r in enumerate(batch):
             r.out.append(int(next_np[i, 0]))

@@ -18,14 +18,15 @@ SCRIPT = textwrap.dedent(
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import OptimConfig, get_reduced
     from repro.distributed.sharding import batch_spec, param_specs
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import build_train_step, make_train_state
     from repro.models.api import ModelSpec
 
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     spec = ModelSpec(get_reduced("qwen3-1.7b"))
     schema = spec.schema()
-    with mesh:
+    with jax.set_mesh(mesh):
         psp = param_specs(schema, mesh)
         p_sh = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), psp)
         state = make_train_state(spec, jax.random.PRNGKey(0))

@@ -40,7 +40,7 @@ def test_paged_attention_sweep(B, H, KV, hd, page, P, N, dtype):
     pt = pt.at[0, N - 1].set(-1)  # one non-resident page
     lengths = jnp.asarray(rng.integers(1, N * page + 1, size=B), jnp.int32)
     ref = paged_decode_attention_ref(q, kp, vp, pt, lengths)
-    out = paged_decode_attention(q, kp, vp, pt, lengths, use_pallas=True)
+    out = paged_decode_attention(q, kp, vp, pt, lengths, mode="interpret")
     tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
     np.testing.assert_allclose(
         np.asarray(ref, np.float32), np.asarray(out, np.float32), atol=tol, rtol=tol
@@ -66,7 +66,7 @@ def test_paged_attention_with_log_merge():
     )
     out = paged_decode_attention(
         q, kp, vp, pt, lengths, log_k, log_v, meta, page_lengths=page_lengths,
-        use_pallas=True,
+        mode="interpret",
     )
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=3e-5, rtol=3e-5)
 
@@ -132,6 +132,6 @@ def test_log_compact_sweep(L, P, page, KV, hd, S, F):
     ft_rows.append([-1, 0, 0])  # padding row
     ft = jnp.asarray(ft_rows, jnp.int32)
     rk, rv = log_compact_ref(kp, vp, log_k, log_v, meta, ft)
-    ok, ov = log_compact(kp, vp, log_k, log_v, meta, ft)
+    ok, ov = log_compact(kp, vp, log_k, log_v, meta, ft, mode="interpret")
     np.testing.assert_allclose(np.asarray(rk), np.asarray(ok), atol=1e-6)
     np.testing.assert_allclose(np.asarray(rv), np.asarray(ov), atol=1e-6)

@@ -6,12 +6,15 @@ context switches, promotion/eviction = adaptive migration) and across log
 compactions — i.e. the paper's mechanisms change performance, never
 results.
 """
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_reduced
+from repro.core import tiering
 from repro.core.tiering import TieredKVConfig
 from repro.models.api import ModelSpec
 from repro.serving.engine import Request, TieredEngine
@@ -44,8 +47,8 @@ def ref_decode(spec, params, prompt, n_new):
     return out
 
 
-def run_engine(spec, params, prompts, kv, n_new, use_pallas=False):
-    eng = TieredEngine(spec, params, kv, use_pallas=use_pallas)
+def run_engine(spec, params, prompts, kv, n_new):
+    eng = TieredEngine(spec, params, kv)
     for rid, p in prompts.items():
         eng.add_request(Request(rid=rid, prompt=p, max_new_tokens=n_new))
     stats = eng.run(max_steps=2000)
@@ -89,8 +92,9 @@ def test_engine_equals_dense_decode(model, case):
         assert stats.compactions > 0
 
 
-def test_engine_pallas_path(model):
+def test_engine_pallas_path(model, monkeypatch):
     """Same equivalence through the Pallas kernels (interpret mode)."""
+    monkeypatch.setattr(tiering, "kernel_mode", lambda: "interpret")
     spec, params = model
     kv = TieredKVConfig(page_size=8, n_hbm_pages=16, max_requests=2,
                         max_pages_per_req=8, log_slots=32, batch=2,
@@ -98,7 +102,7 @@ def test_engine_pallas_path(model):
     prompts = {0: list(range(3, 19)), 1: list(range(21, 40))}
     n_new = 10
     refs = {rid: ref_decode(spec, params, p, n_new) for rid, p in prompts.items()}
-    eng, stats = run_engine(spec, params, prompts, kv, n_new, use_pallas=True)
+    eng, stats = run_engine(spec, params, prompts, kv, n_new)
     for rid in prompts:
         assert eng.requests[rid].out == refs[rid]
 
@@ -117,3 +121,53 @@ def test_coalescing_reduces_page_writes(model):
     # flushed pages must be well below decoded tokens
     assert stats.flushed_pages < stats.decoded_tokens
     assert stats.coalesce_ratio > 1.5
+
+
+def test_serve_run_agrees_with_dense_baseline():
+    """The entry point chip_smoke.py drives, at reduced width: under pool
+    pressure and compaction every request completes, every mechanism fires,
+    and the dense baseline fed the tiered tokens picks each one itself."""
+    from repro.launch import serve
+
+    n_new = 40  # 4 x 39 decoded tokens fill the 64-slot log twice
+    args = serve.build_parser().parse_args([
+        "--requests", "4", "--prompt-len", "20", "--new-tokens", str(n_new),
+        "--page-size", "8", "--hbm-pages", "10", "--batch", "2",
+        "--promote-pages", "2",
+    ])
+    res = serve.run(args)
+    eng = res["engine"]
+    assert all(r.done for r in eng.requests.values())
+    st = eng.stats
+    assert min(st.parks, st.promoted_pages, st.evicted_pages, st.compactions) > 0
+    assert set(eng.compile_seconds) == {"decode", "compact"}
+    _, gaps = serve.baseline_serve(res["spec"], res["params"], res["prompts"],
+                                   n_new, follow=res["outs"])
+    assert sorted(gaps) == sorted(res["prompts"])
+    assert all(len(g) == n_new and max(g) == 0.0 for g in gaps.values())
+
+
+@pytest.mark.parametrize("env_dir", [None, "placed-from-outside"])
+def test_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise one fixed
+    directory in the checkout."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            got = compile_cache.enable_compile_cache()
+            assert got == str(compile_cache.REPO_CACHE_DIR)
+            assert compile_cache.REPO_CACHE_DIR.parent == (
+                Path(__file__).resolve().parent.parent
+            )
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            want = str(tmp_path / env_dir)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+            jax.config.update("jax_compilation_cache_dir", want)  # as read at import
+            assert compile_cache.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
