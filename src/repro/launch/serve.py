@@ -7,9 +7,11 @@
 
 Reports the paper's metrics for the serving analogue: parks (coordinated
 context switches), promoted/evicted pages (adaptive migration), compactions
-and the coalescing ratio (write-log). Compilation of the decode step and
-the compaction is reported as set-up time. The wall times printed are host
-clock readings that include prefill compilation; they are not measurements.
+and the coalescing ratio (write-log), and the engine's device->host reads
+(``ServeStats.host_reads``), the host round trips its policy makes.
+Compilation of the decode step and the compaction is reported as set-up
+time. The wall times printed are host clock readings that include prefill
+compilation; they are not measurements.
 Weights are random (bf16, drawn from --seed); prompts are random tokens.
 """
 from __future__ import annotations
@@ -150,6 +152,8 @@ def run(args: argparse.Namespace) -> Dict[str, Any]:
     print(f"  promoted / evicted pages  : {stats.promoted_pages} / {stats.evicted_pages}")
     print(f"  compactions               : {stats.compactions}")
     print(f"  coalesce ratio (tok/page) : {stats.coalesce_ratio:.2f}")
+    print(f"  host reads (device->host) : {stats.host_reads} "
+          f"({stats.host_reads / max(stats.steps, 1):.1f} per step, admission included)")
     done = sum(r.done for r in eng.requests.values())
     print(f"  completed requests        : {done}/{len(eng.requests)}")
     res["engine"] = eng

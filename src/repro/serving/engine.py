@@ -20,23 +20,30 @@ Per decode step:
      resident pages (HBM) and parked pages (host tier), then swap-clear.
 
 Stats mirror the simulator's so the TPU runtime can be judged with the
-paper's own metrics (coalescing ratio, switch count, fetch traffic).
+paper's own metrics (coalescing ratio, switch count, fetch traffic), and
+count the device->host reads the policy makes (``host_reads``).
+
+While a profile is recorded, ``step`` marks each phase with a host span
+(``tiered.compact``, ``tiered.residency``, ``tiered.promote``,
+``tiered.decode``, ``tiered.lru``) on the profiler's clock, which the
+device's events share; outside a profile the spans record nothing.
 
 The decode step and the compaction are compiled ahead of time when the
 engine is built (``compile_seconds`` records the set-up time of each, and
-``step_fn``/``compact_fn`` are the compiled programs); their kernel path
-follows the platform (``tiering.kernel_mode``).
+``step_fn``/``compact_fn`` are the compiled programs, named
+``jit_step`` and ``jit_compact_log``); their kernel path follows the
+platform (``tiering.kernel_mode``).
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import tiering
 from repro.core.tiering import TieredKVConfig, host_slot
@@ -63,6 +70,7 @@ class ServeStats:
     compactions: int = 0
     flushed_pages: int = 0
     flushed_tokens: int = 0
+    host_reads: int = 0  # device->host reads (TieredEngine._fetch)
 
     @property
     def coalesce_ratio(self) -> float:
@@ -90,11 +98,14 @@ class TieredEngine:
             params, self.state,
             jax.ShapeDtypeStruct((B, 1), i32), jax.ShapeDtypeStruct((B,), i32),
         )
-        # the pools are donated: compaction rewrites them in place
+        # named for its program, "jit_compact_log" (a fresh function per
+        # engine, as the step is, so each traces its own kernel path); the
+        # pools are donated: compaction rewrites them in place
+        def compact_log(state, flush_hbm, flush_host):
+            return tiering.compact_log(kv_cfg, state, flush_hbm, flush_host)
+
         self.compact_fn = self._compile(
-            "compact",
-            jax.jit(functools.partial(tiering.compact_log, kv_cfg),
-                    donate_argnums=0),
+            "compact", jax.jit(compact_log, donate_argnums=0),
             self.state, flush, flush,
         )
         self.prefill_fn = jax.jit(spec.prefill)  # compiles once per length
@@ -110,6 +121,11 @@ class TieredEngine:
         compiled = fn.lower(*args).compile()
         self.compile_seconds[name] = time.perf_counter() - t0
         return compiled
+
+    def _fetch(self, x):
+        """Every device->host read of the engine, counted."""
+        self.stats.host_reads += 1
+        return jax.device_get(x)
 
     # ---- admission ----
     def add_request(self, req: Request) -> None:
@@ -132,7 +148,7 @@ class TieredEngine:
             self.kv, self.state, rid, k, v
         )
         # the prompt's next token comes from the prefill logits
-        req.out.append(int(jnp.argmax(logits[0])))
+        req.out.append(int(self._fetch(jnp.argmax(logits[0]))))
         req.served += 1
         self.stats.decoded_tokens += 1
 
@@ -140,12 +156,12 @@ class TieredEngine:
     def _pages_needed(self, req: Request) -> List[int]:
         # attention reads pages only below the compaction watermark; newer
         # positions live in the (always-resident) write log
-        compacted = int(self.state["compacted"][req.rid])
+        compacted = int(self._fetch(self.state["compacted"][req.rid]))
         n = (compacted + self.kv.page_size - 1) // self.kv.page_size
         return list(range(n))
 
     def _resident(self, rid: int, logical: int) -> bool:
-        return int(self.state["page_table"][rid, logical]) >= 0
+        return int(self._fetch(self.state["page_table"][rid, logical])) >= 0
 
     def _free_slot(self, protect: set) -> Optional[int]:
         for s, owner in enumerate(self.hbm_owner):
@@ -184,7 +200,7 @@ class TieredEngine:
 
     # ---- compaction ----
     def _compact(self) -> None:
-        meta = np.asarray(self.state["log_meta"])
+        meta = self._fetch(self.state["log_meta"])
         dirty = {}
         for owner, pos in meta:
             if owner >= 0 and pos >= 0:
@@ -192,7 +208,7 @@ class TieredEngine:
                 dirty[(int(owner), int(pos) // self.kv.page_size)] += 1
         flush_hbm, flush_host = [], []
         for (rid, logical), ntok in sorted(dirty.items()):
-            slot = int(self.state["page_table"][rid, logical])
+            slot = int(self._fetch(self.state["page_table"][rid, logical]))
             if slot >= 0:
                 flush_hbm.append([rid, logical, slot])
             # ALWAYS flush to the host backing store (write-back tier);
@@ -221,59 +237,66 @@ class TieredEngine:
         # 0. compact BEFORE the residency check: compaction advances the
         # watermark, which can create page demand — readiness must be
         # evaluated against the post-compaction layout
-        if int(self.state["log_tail"]) + self.kv.batch > self.kv.log_slots:
-            self._compact()
+        if int(self._fetch(self.state["log_tail"])) + self.kv.batch > self.kv.log_slots:
+            with TraceAnnotation("tiered.compact"):
+                self._compact()
         # 1. residency + parking (the coordinated context switch)
-        ready, parked = [], []
-        for r in active:
-            missing = [p for p in self._pages_needed(r) if not self._resident(r.rid, p)]
-            if missing:
-                parked.append((r, missing))
-            else:
-                ready.append(r)
+        with TraceAnnotation("tiered.residency"):
+            ready, parked = [], []
+            for r in active:
+                missing = [p for p in self._pages_needed(r)
+                           if not self._resident(r.rid, p)]
+                if missing:
+                    parked.append((r, missing))
+                else:
+                    ready.append(r)
         # 2. promotion budget — closest-to-ready parked request first (SJF:
         # guarantees progress), just-promoted pages join the protect set so
         # the budget loop cannot evict its own work
-        budget = self.kv.promote_pages_per_step
-        protect = {(r.rid, p) for r in ready for p in self._pages_needed(r)}
-        parked.sort(key=lambda rm: len(rm[1]))
-        for r, missing in parked:
-            self.stats.parks += 1
-            for p in missing:
-                if budget <= 0:
-                    break
-                if self._promote(r.rid, p, protect):
-                    protect.add((r.rid, p))
-                    budget -= 1
+        with TraceAnnotation("tiered.promote"):
+            budget = self.kv.promote_pages_per_step
+            protect = {(r.rid, p) for r in ready for p in self._pages_needed(r)}
+            parked.sort(key=lambda rm: len(rm[1]))
+            for r, missing in parked:
+                self.stats.parks += 1
+                for p in missing:
+                    if budget <= 0:
+                        break
+                    if self._promote(r.rid, p, protect):
+                        protect.add((r.rid, p))
+                        budget -= 1
         # 3. schedule ready requests, least-served first (CFS)
         ready.sort(key=lambda r: r.served)
         batch = ready[: self.kv.batch]
         if not batch:
             return
         # 4. decode one token for the batch
-        B = self.kv.batch
-        req_ids = np.full((B,), -1, np.int32)
-        tokens = np.zeros((B, 1), np.int32)
-        for i, r in enumerate(batch):
-            req_ids[i] = r.rid
-            last = r.out[-1] if r.out else r.prompt[-1]
-            tokens[i, 0] = last
-        next_tok, updates = self.step_fn(
-            self.params, self.state, jnp.asarray(tokens), jnp.asarray(req_ids)
-        )
-        self.state.update(updates)
-        next_np = np.asarray(next_tok)
+        with TraceAnnotation("tiered.decode"):
+            B = self.kv.batch
+            req_ids = np.full((B,), -1, np.int32)
+            tokens = np.zeros((B, 1), np.int32)
+            for i, r in enumerate(batch):
+                req_ids[i] = r.rid
+                last = r.out[-1] if r.out else r.prompt[-1]
+                tokens[i, 0] = last
+            next_tok, updates = self.step_fn(
+                self.params, self.state, jnp.asarray(tokens), jnp.asarray(req_ids)
+            )
+            self.state.update(updates)
+            next_np = self._fetch(next_tok)
         for i, r in enumerate(batch):
             r.out.append(int(next_np[i, 0]))
             r.served += 1
-            # touch LRU for this request's pages
-            for p in self._pages_needed(r):
-                s = int(self.state["page_table"][r.rid, p])
-                if s >= 0:
-                    self.lru[s] = self._clock
             if r.served >= r.max_new_tokens:
                 r.done = True
             self.stats.decoded_tokens += 1
+        # touch the LRU stamps of the scheduled requests' pages
+        with TraceAnnotation("tiered.lru"):
+            for r in batch:
+                for p in self._pages_needed(r):
+                    s = int(self._fetch(self.state["page_table"][r.rid, p]))
+                    if s >= 0:
+                        self.lru[s] = self._clock
         self.stats.steps += 1
 
     def run(self, max_steps: int = 1000) -> ServeStats:
