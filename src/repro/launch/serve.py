@@ -8,7 +8,7 @@
 Reports the paper's metrics for the serving analogue: parks (coordinated
 context switches), promoted/evicted pages (adaptive migration), compactions
 and the coalescing ratio (write-log), and the engine's device->host reads
-(``ServeStats.host_reads``), the host round trips its policy makes.
+(``ServeStats.host_reads``): the tokens of each prefill and decode step.
 Compilation of the decode step and the compaction is reported as set-up
 time. The wall times printed are host clock readings that include prefill
 compilation; they are not measurements.

@@ -19,9 +19,17 @@ Per decode step:
   5. compaction — when the log can't hold another step, coalesce it into
      resident pages (HBM) and parked pages (host tier), then swap-clear.
 
+The host owns the engine's metadata. The page mapping (``page_table``,
+``hbm_owner``, ``lru``) is written only here and sent to the device once a
+step, before the decode, when it changed. The compaction watermarks, the
+lengths, the log tail and the log's dirty pages are host copies of what the
+device programs write, kept at the point where the engine launches each
+write; every policy decision reads them and none reads the device.
+
 Stats mirror the simulator's so the TPU runtime can be judged with the
 paper's own metrics (coalescing ratio, switch count, fetch traffic), and
-count the device->host reads the policy makes (``host_reads``).
+count the device->host reads the engine makes (``host_reads``): one token
+per prefill at admission, one token read per step that decodes.
 
 While a profile is recorded, ``step`` marks each phase with a host span
 (``tiered.compact``, ``tiered.residency``, ``tiered.promote``,
@@ -110,9 +118,19 @@ class TieredEngine:
         )
         self.prefill_fn = jax.jit(spec.prefill)  # compiles once per length
         self.requests: Dict[int, Request] = {}
-        # host-side metadata
+        # host-side metadata: the page mapping, owned here ...
+        self.page_table = np.full(
+            (kv_cfg.max_requests, kv_cfg.max_pages_per_req), -1, np.int32)
+        self._table_changed = False  # since it was last sent to the device
         self.hbm_owner: List[Optional[tuple]] = [None] * kv_cfg.n_hbm_pages
         self.lru: np.ndarray = np.zeros(kv_cfg.n_hbm_pages, np.int64)
+        # ... and copies of the device's watermarks, lengths and log tail,
+        # with the log's dirty pages {(rid, logical): tokens} since the last
+        # compaction (what log_meta holds)
+        self.compacted = np.zeros(kv_cfg.max_requests, np.int32)
+        self.lengths = np.zeros(kv_cfg.max_requests, np.int32)
+        self.log_tail = 0
+        self.dirty: Dict[tuple, int] = {}
         self.stats = ServeStats()
         self._clock = 0
 
@@ -123,7 +141,8 @@ class TieredEngine:
         return compiled
 
     def _fetch(self, x):
-        """Every device->host read of the engine, counted."""
+        """Every device->host read of the engine, counted: the tokens of a
+        prefill and of a decode step. The policy reads only host state."""
         self.stats.host_reads += 1
         return jax.device_get(x)
 
@@ -147,21 +166,21 @@ class TieredEngine:
         self.state = tiering.write_prefill_pages(
             self.kv, self.state, rid, k, v
         )
+        self.lengths[rid] = self.compacted[rid] = len(req.prompt)
         # the prompt's next token comes from the prefill logits
         req.out.append(int(self._fetch(jnp.argmax(logits[0]))))
         req.served += 1
         self.stats.decoded_tokens += 1
 
     # ---- residency / promotion ----
-    def _pages_needed(self, req: Request) -> List[int]:
+    def _n_pages(self, rid: int) -> int:
         # attention reads pages only below the compaction watermark; newer
         # positions live in the (always-resident) write log
-        compacted = int(self._fetch(self.state["compacted"][req.rid]))
-        n = (compacted + self.kv.page_size - 1) // self.kv.page_size
-        return list(range(n))
+        return (int(self.compacted[rid]) + self.kv.page_size - 1) // self.kv.page_size
 
-    def _resident(self, rid: int, logical: int) -> bool:
-        return int(self._fetch(self.state["page_table"][rid, logical])) >= 0
+    def _missing(self, rid: int) -> List[int]:
+        """The pages request ``rid`` needs that are not HBM-resident."""
+        return np.flatnonzero(self.page_table[rid, :self._n_pages(rid)] < 0).tolist()
 
     def _free_slot(self, protect: set) -> Optional[int]:
         for s, owner in enumerate(self.hbm_owner):
@@ -175,9 +194,8 @@ class TieredEngine:
         for s in order:
             if self.hbm_owner[s] is not None and self.hbm_owner[s] not in protect:
                 rid, logical = self.hbm_owner[s]
-                self.state["page_table"] = self.state["page_table"].at[
-                    rid, logical
-                ].set(-1)
+                self.page_table[rid, logical] = -1
+                self._table_changed = True
                 self.hbm_owner[s] = None
                 self.stats.evicted_pages += 1
                 return int(s)
@@ -192,7 +210,8 @@ class TieredEngine:
             self.state["hbm_k"], self.state["hbm_v"],
             self.state["host_k"], self.state["host_v"], pairs,
         )
-        self.state["page_table"] = self.state["page_table"].at[rid, logical].set(slot)
+        self.page_table[rid, logical] = slot
+        self._table_changed = True
         self.hbm_owner[slot] = (rid, logical)
         self.lru[slot] = self._clock
         self.stats.promoted_pages += 1
@@ -200,15 +219,9 @@ class TieredEngine:
 
     # ---- compaction ----
     def _compact(self) -> None:
-        meta = self._fetch(self.state["log_meta"])
-        dirty = {}
-        for owner, pos in meta:
-            if owner >= 0 and pos >= 0:
-                dirty.setdefault((int(owner), int(pos) // self.kv.page_size), 0)
-                dirty[(int(owner), int(pos) // self.kv.page_size)] += 1
         flush_hbm, flush_host = [], []
-        for (rid, logical), ntok in sorted(dirty.items()):
-            slot = int(self._fetch(self.state["page_table"][rid, logical]))
+        for (rid, logical), ntok in sorted(self.dirty.items()):
+            slot = int(self.page_table[rid, logical])
             if slot >= 0:
                 flush_hbm.append([rid, logical, slot])
             # ALWAYS flush to the host backing store (write-back tier);
@@ -220,6 +233,9 @@ class TieredEngine:
         self.state = self.compact_fn(
             self.state, self._flush_rows(flush_hbm), self._flush_rows(flush_host)
         )
+        self.compacted[:] = self.lengths
+        self.log_tail = 0
+        self.dirty = {}
         self.stats.compactions += 1
 
     def _flush_rows(self, rows: List[List[int]]) -> jax.Array:
@@ -237,15 +253,14 @@ class TieredEngine:
         # 0. compact BEFORE the residency check: compaction advances the
         # watermark, which can create page demand — readiness must be
         # evaluated against the post-compaction layout
-        if int(self._fetch(self.state["log_tail"])) + self.kv.batch > self.kv.log_slots:
+        if self.log_tail + self.kv.batch > self.kv.log_slots:
             with TraceAnnotation("tiered.compact"):
                 self._compact()
         # 1. residency + parking (the coordinated context switch)
         with TraceAnnotation("tiered.residency"):
             ready, parked = [], []
             for r in active:
-                missing = [p for p in self._pages_needed(r)
-                           if not self._resident(r.rid, p)]
+                missing = self._missing(r.rid)
                 if missing:
                     parked.append((r, missing))
                 else:
@@ -255,7 +270,7 @@ class TieredEngine:
         # the budget loop cannot evict its own work
         with TraceAnnotation("tiered.promote"):
             budget = self.kv.promote_pages_per_step
-            protect = {(r.rid, p) for r in ready for p in self._pages_needed(r)}
+            protect = {(r.rid, p) for r in ready for p in range(self._n_pages(r.rid))}
             parked.sort(key=lambda rm: len(rm[1]))
             for r, missing in parked:
                 self.stats.parks += 1
@@ -265,6 +280,11 @@ class TieredEngine:
                     if self._promote(r.rid, p, protect):
                         protect.add((r.rid, p))
                         budget -= 1
+            if self._table_changed:
+                # a copy: on the CPU backend the device array may alias the
+                # host buffer, which later edits would then reach
+                self.state["page_table"] = jnp.asarray(self.page_table.copy())
+                self._table_changed = False
         # 3. schedule ready requests, least-served first (CFS)
         ready.sort(key=lambda r: r.served)
         batch = ready[: self.kv.batch]
@@ -283,6 +303,12 @@ class TieredEngine:
                 self.params, self.state, jnp.asarray(tokens), jnp.asarray(req_ids)
             )
             self.state.update(updates)
+            for r in batch:
+                n = int(self.lengths[r.rid])
+                key = (r.rid, n // self.kv.page_size)
+                self.dirty[key] = self.dirty.get(key, 0) + 1
+                self.lengths[r.rid] = n + 1
+            self.log_tail += B
             next_np = self._fetch(next_tok)
         for i, r in enumerate(batch):
             r.out.append(int(next_np[i, 0]))
@@ -293,10 +319,8 @@ class TieredEngine:
         # touch the LRU stamps of the scheduled requests' pages
         with TraceAnnotation("tiered.lru"):
             for r in batch:
-                for p in self._pages_needed(r):
-                    s = int(self._fetch(self.state["page_table"][r.rid, p]))
-                    if s >= 0:
-                        self.lru[s] = self._clock
+                slots = self.page_table[r.rid, :self._n_pages(r.rid)]
+                self.lru[slots[slots >= 0]] = self._clock
         self.stats.steps += 1
 
     def run(self, max_steps: int = 1000) -> ServeStats:

@@ -44,28 +44,22 @@ def engine(model, kv, n_new=20):
 
 
 def reads_before_step(eng):
-    """The reads the next step makes up to its decode, from the state before
-    it: the log tail; on a compaction step the log metadata and one page
-    table entry per dirty page; per active request its watermark and one
-    entry per page it needs; per ready request its watermark again, for the
-    protect set. Returns that count, whether the step compacts, the ready
-    requests and the pages each active request needs."""
+    """The reads the next step makes, from the device's state before it: the
+    policy reads only host state, so the step reads its tokens if it
+    decodes, which it does iff a request is ready once a compaction due
+    now has moved the watermarks. Returns that count, whether the step
+    compacts, the ready requests and the pages each active request needs."""
     kv = eng.kv
     st = {k: np.asarray(eng.state[k])
-          for k in ("log_tail", "log_meta", "compacted", "lengths", "page_table")}
+          for k in ("log_tail", "compacted", "lengths", "page_table")}
     active = [r for r in eng.requests.values() if not r.done]
-    n = 1
     compacts = int(st["log_tail"]) + kv.batch > kv.log_slots
-    compacted = st["compacted"]
-    if compacts:
-        dirty = {(o, p // kv.page_size) for o, p in st["log_meta"] if o >= 0 and p >= 0}
-        n += 1 + len(dirty)
-        compacted = st["lengths"]  # compaction moves the watermark to the end
+    # compaction moves the watermark to the end
+    compacted = st["lengths"] if compacts else st["compacted"]
     pages = {r.rid: -(-int(compacted[r.rid]) // kv.page_size) for r in active}
-    n += sum(1 + pages[r.rid] for r in active)
     ready = {r.rid for r in active
              if (st["page_table"][r.rid, :pages[r.rid]] >= 0).all()}
-    return n + len(ready), compacts, ready, pages
+    return int(bool(ready)), compacts, ready, pages
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -80,8 +74,7 @@ def test_host_reads_counted_on_every_step(model, case):
         eng.step()
         scheduled = [rid for rid, r in eng.requests.items() if len(r.out) > served[rid]]
         assert set(scheduled) <= ready
-        if scheduled:  # LRU touch: watermark and page entries, then the tokens
-            want += sum(1 + pages[rid] for rid in scheduled) + 1
+        assert bool(scheduled) == bool(ready)
         assert eng.stats.host_reads - before == want, eng.stats.steps
         compaction_steps += compacts
         parked_steps += len(ready) < len(pages)

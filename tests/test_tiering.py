@@ -92,6 +92,38 @@ def test_engine_equals_dense_decode(model, case):
         assert stats.compactions > 0
 
 
+def assert_host_metadata_mirrors_device(eng):
+    st = {k: np.asarray(eng.state[k])
+          for k in ("page_table", "compacted", "lengths", "log_tail", "log_meta")}
+    np.testing.assert_array_equal(eng.page_table, st["page_table"])
+    np.testing.assert_array_equal(eng.compacted, st["compacted"])
+    np.testing.assert_array_equal(eng.lengths, st["lengths"])
+    assert eng.log_tail == int(st["log_tail"])
+    dirty = {}
+    for owner, pos in st["log_meta"]:
+        if owner >= 0 and pos >= 0:
+            key = (int(owner), int(pos) // eng.kv.page_size)
+            dirty[key] = dirty.get(key, 0) + 1
+    assert eng.dirty == dirty
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_host_metadata_mirrors_device(model, case):
+    """The page table, watermarks, lengths, log tail and dirty pages the
+    policy reads on the host equal the device's after every step, so also
+    just before each compaction, which flushes the dirty pages."""
+    spec, params = model
+    eng = TieredEngine(spec, params, CASES[case])
+    for rid, p in {0: list(range(7, 27)), 1: list(range(40, 75)),
+                   2: list(range(5, 18))}.items():
+        eng.add_request(Request(rid=rid, prompt=p, max_new_tokens=20))
+    assert_host_metadata_mirrors_device(eng)
+    while not all(r.done for r in eng.requests.values()):
+        eng.step()
+        assert_host_metadata_mirrors_device(eng)
+    assert eng.stats.steps > 0
+
+
 def test_engine_pallas_path(model, monkeypatch):
     """Same equivalence through the Pallas kernels (interpret mode)."""
     monkeypatch.setattr(tiering, "kernel_mode", lambda: "interpret")
