@@ -14,31 +14,23 @@ same sequences it reads the gap of the token the control ranks first.
 """
 from __future__ import annotations
 
-import importlib.util
 from pathlib import Path
 
 import numpy as np
 
+from bench import refs
 from bench.weights import make
 
 
-def reference_module(root: Path, config: dict):
-    path = root / "bench" / "reference" / f"{config['reference']}.py"
-    spec = importlib.util.spec_from_file_location(f"ref_{config['reference']}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def gaps(root: Path, config: dict, model_cfg, seed: int, served: dict,
+def gaps(root: Path, config: dict, seed: int, served: dict,
          *, control: bool = False) -> dict:
     """{rid: widest gap of that request's served tokens}. `served` maps rid
     to (prompt, out). With `control`, the gap of the control's first-ranked
     token at each position instead of the served one."""
     import jax.numpy as jnp
 
-    ref = reference_module(root, config)
-    params = make(model_cfg, seed)
+    ref = refs.load(root, config)
+    params = make(config, seed, root=root)
     out = {}
     for rid, (prompt, toks) in sorted(served.items()):
         logits = ref.served_logits(config, params, prompt, toks)

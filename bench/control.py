@@ -30,10 +30,10 @@ def readings(cell, seed: int, seconds: float, log=sys.stderr) -> dict:
     from bench import check, harness
 
     t = time.perf_counter()
-    result, served, cfg = harness.measure(cell, seed, seconds, False, t, log)
+    result, served = harness.measure(cell, seed, seconds, False, t, log)
     gc.collect()
-    program = check.gaps(cell.root, cell.config, cfg, seed, served)
-    control = check.gaps(cell.root, cell.config, cfg, seed, served, control=True)
+    program = check.gaps(cell.root, cell.config, seed, served)
+    control = check.gaps(cell.root, cell.config, seed, served, control=True)
     return {"seed": seed, "program": max(program.values()),
             "control": max(control.values()),
             "served_tokens": sum(len(o) for _, o in served.values()),

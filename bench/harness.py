@@ -5,8 +5,21 @@ Everything that belongs to a cell is found by name from `BENCHMARK.json`:
 the configuration file it names, `bench/traffic/<traffic>.json`,
 `bench/metrics/<metric>.py` for each per-layer metric and
 `bench/limits/<cell>.json` for the correctness limit. The program is used
-only through `TieredEngine`, `Request`, `add_request`, `step`, `requests`
-and `stats`.
+only through `ModelConfig`, `TieredEngine`, `Request`, `add_request`,
+`step`, `requests` and `stats`.
+
+A configuration of any family is added as files alone:
+
+- `bench/configs/<name>.json`: the program's `ModelConfig` fields (a dict
+  under a field that is a dataclass, such as `moe`, fills that dataclass),
+  any keys of the benchmark's own, and `reference`, the name of its module;
+- `bench/reference/<reference>.py`, the plain reference, which imports
+  nothing of the program. It defines `served_logits(config, params, prompt,
+  out, *, control=False)`, as `dense_gqa.py` does, and where the
+  configuration is not a dense GQA decoder also `shapes(config)`, the
+  weights' leaf table (`bench/weights.py`), and `decode_token_flops` and
+  `paged_attn_work`, its work counts (`bench/flops.py`);
+- its cells' traffic mixes, limit files and any per-layer metric readers.
 """
 from __future__ import annotations
 
@@ -17,6 +30,7 @@ import json
 import sys
 import tempfile
 import time
+import typing
 from pathlib import Path
 
 import numpy as np
@@ -105,10 +119,32 @@ class CompileCounter:
 
 
 def model_config(config: dict):
+    """The program's `ModelConfig` from a configuration file's dict. Keys
+    that are not its fields stay for the benchmark's own use."""
     from repro.configs.base import ModelConfig
 
-    names = {f.name for f in dataclasses.fields(ModelConfig)}
-    return ModelConfig(**{k: v for k, v in config.items() if k in names})
+    return _dataclass(ModelConfig, config)
+
+
+def _dataclass(cls, config: dict):
+    """`cls` from the dict's keys that are its fields. A dict under a field
+    whose type is a dataclass becomes that dataclass the same way; lists
+    become tuples, as a frozen config holds its sequences."""
+    types = typing.get_type_hints(cls)
+    kw = {}
+    for f in dataclasses.fields(cls):
+        if f.name not in config:
+            continue
+        v = config[f.name]
+        inner = [t for t in (types[f.name], *typing.get_args(types[f.name]))
+                 if dataclasses.is_dataclass(t)]
+        kw[f.name] = (_dataclass(inner[0], v) if isinstance(v, dict) and inner
+                      else _tuples(v))
+    return cls(**kw)
+
+
+def _tuples(v):
+    return tuple(_tuples(x) for x in v) if isinstance(v, list) else v
 
 
 def _stats(eng) -> dict:
@@ -131,7 +167,7 @@ def itl_samples(token_times: dict, done: dict, t0: float, t1: float) -> list:
 def measure(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
             log=sys.stderr, keep_trace: str | None = None):
     """Set-up and the measured window. Returns (result without the check,
-    {rid: (prompt, served tokens)}, model config). The caller has checked
+    {rid: (prompt, served tokens)}). The caller has checked
     the device; `t_start` is the process's start on the host clock. With
     `keep_trace`, the profile is written under that directory and kept."""
     import jax
@@ -146,7 +182,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     compiles = CompileCounter()
     cfg = model_config(cell.config)
     reqs = traffic_gen.requests(cell.mix, seed, cfg.vocab)
-    params = jax.block_until_ready(weights.make(cfg, seed))
+    params = jax.block_until_ready(weights.make(cell.config, seed, root=cell.root))
     say(f"weights at {time.perf_counter() - t_start:.3f} s")
     kv = TieredKVConfig(**cell.mix["engine"], max_requests=len(reqs))
     eng = TieredEngine(ModelSpec(cfg), params, kv)
@@ -253,15 +289,15 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     result.update(metrics=metrics, device=device)
     if breakdown is not None:
         result["breakdown"] = breakdown
-    return result, served, cfg
+    return result, served
 
 
-def judge(cell: Cell, cfg, seed: int, served: dict, result: dict,
+def judge(cell: Cell, seed: int, served: dict, result: dict,
           log=sys.stderr) -> dict:
     """Compare the served tokens with the reference; fill in `correct`,
     `failed` and, last, `check`. Runs after the program's state is freed."""
     t = time.perf_counter()
-    gaps = check.gaps(cell.root, cell.config, cfg, seed, served)
+    gaps = check.gaps(cell.root, cell.config, seed, served)
     limit = cell.limits.get("max_logit_gap", {}).get("limit")
     widest = max(gaps.values())
     failed = sum(g > limit for g in gaps.values()) if limit is not None else len(gaps)
@@ -277,6 +313,6 @@ def judge(cell: Cell, cfg, seed: int, served: dict, result: dict,
 def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         log=sys.stderr, keep_trace: str | None = None) -> dict:
     """One run of the cell; returns the result line's dict."""
-    result, served, cfg = measure(cell, seed, seconds, trace, t_start, log, keep_trace)
+    result, served = measure(cell, seed, seconds, trace, t_start, log, keep_trace)
     gc.collect()  # the engine, its pools and the weights are gone by now
-    return judge(cell, cfg, seed, served, result, log)
+    return judge(cell, seed, served, result, log)

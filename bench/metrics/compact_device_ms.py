@@ -1,11 +1,12 @@
 """Device time of one call of the compiled log compaction, in ms. The
-program is `functools.partial(tiering.compact_log, kv)` under `jax.jit`,
-whose HLO module is named "jit__unknown" (a partial has no name), so it is
-found instead as the module that runs the compaction kernel, the Pallas
-call whose HLO instruction is named "log_compact_pallas.<n>" (both names
-read from the compiled program's HLO for a v5e). A window in which no
-compaction ran reports nothing; one whose counters count compactions but
-whose trace holds no such module is an error."""
+engine jits a local function named `compact_log` around
+`tiering.compact_log`, whose HLO module is named "jit_compact_log". The
+reader does not depend on that name: it finds the program as the module
+that runs the compaction kernel, the Pallas
+call whose HLO instruction is named "log_compact_pallas.<n>" (read from the
+compiled program's HLO for a v5e). A window in which no compaction ran
+reports nothing; one whose counters count compactions but whose trace holds
+no such module is an error."""
 
 KERNEL = "log_compact_pallas"
 

@@ -2,15 +2,18 @@
 
 The least time the chip needs for the window's paged-attention calls is the
 larger of their bytes over peak HBM bandwidth and their FLOPs over peak
-bf16 FLOP/s (bench/flops.py; bytes: K and V of each scheduled row's valid
-pages, counted from below, plus q and the output). The kernel's time is the
-device time of its custom call inside the decode step (module "jit_step")
-on the trace's "XLA Ops" line. The compiled step names that call after the
-function that wraps the `pallas_call`, "paged_decode_attention_pallas.<n>"
-(read from the compiled program's HLO for a v5e). A decode step that ran
-with no such op is an error, not a missing reading.
+bf16 FLOP/s. Both come from `bench/flops.py::paged_attn_work`, one decode
+step at a time: the configuration's reference module's own count where it
+defines one, else the dense count (bytes: K and V of each scheduled row's
+valid pages, counted from below, plus q and the output). The kernel's time
+is the device time of its custom call inside the decode step (module
+"jit_step") on the trace's "XLA Ops" line. The compiled step names that
+call after the function that wraps the `pallas_call`,
+"paged_decode_attention_pallas.<n>" (read from the compiled program's HLO
+for a v5e). A decode step that ran with no such op is an error, not a
+missing reading.
 """
-from bench.flops import paged_attn_bytes, paged_pages_lower_bound
+from bench.flops import paged_attn_work
 
 MODULE, KERNEL = "jit_step", "paged_decode_attention_pallas"
 
@@ -22,15 +25,11 @@ def read(run):
         return None
     if not ns:
         raise ValueError(f"no {KERNEL} op inside {MODULE} in the trace: {sorted(ops)[:20]}")
-    cfg, eng = run.cell.config, run.cell.mix["engine"]
-    page = eng["page_size"]
+    cfg, eng, root = run.cell.config, run.cell.mix["engine"], run.cell.root
     nbytes = flops = 0
     for s in run.steps:
-        pages = [paged_pages_lower_bound(c, page, eng["log_slots"], eng["batch"])
-                 for c in s.contexts]
-        nbytes += paged_attn_bytes(cfg, pages, page)
-        flops += cfg["n_layers"] * sum(4 * cfg["n_heads"] * cfg["head_dim"] * p * page
-                                       for p in pages)
+        b, f = paged_attn_work(cfg, s.contexts, eng, root=root)
+        nbytes, flops = nbytes + b, flops + f
     least = max(nbytes / run.peaks["hbm_bytes_per_s"],
                 flops / run.peaks["bf16_flops_per_s"])
     return 100.0 * least / (ns / 1e9)

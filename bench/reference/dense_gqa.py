@@ -57,9 +57,9 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _layer(cfg, control, x, p):
-    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-    p = jax.tree_util.tree_map(f32, p)
+def attention(cfg, control, x, p):
+    """x plus the attention block's output; `p` holds one layer's leaves in
+    float32."""
     S = x.shape[0]
     H, KV, hd, eps = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"], cfg["norm_eps"]
     h = _rms(x, p["attn_norm"], eps)
@@ -78,8 +78,14 @@ def _layer(cfg, control, x, p):
     causal = jnp.tril(jnp.ones((S, S), bool))
     w = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
     o = _mm("hqk,khd->qhd", w, v, control).reshape(S, H * hd)
-    x = x + _mm("sh,hd->sd", o, p["wo"], control)
-    h2 = _rms(x, p["mlp_norm"], eps)
+    return x + _mm("sh,hd->sd", o, p["wo"], control)
+
+
+def _layer(cfg, control, x, p):
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    p = jax.tree_util.tree_map(f32, p)
+    x = attention(cfg, control, x, p)
+    h2 = _rms(x, p["mlp_norm"], cfg["norm_eps"])
     a = jax.nn.silu(_mm("sd,df->sf", h2, p["w_gate"], control))
     u = _mm("sd,df->sf", h2, p["w_up"], control)
     return x + _mm("sf,fd->sd", a * u, p["w_down"], control), None

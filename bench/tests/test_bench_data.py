@@ -127,3 +127,103 @@ def test_benchmark_json_keeps_the_contract_shape():
         assert set(g) >= {"limit", "lower", "upper", "readings"}
         if g["limit"] is not None:  # a limit lies between its two readings
             assert g["lower"] < g["limit"] < g["upper"]
+
+
+def _parent_table(cfg):
+    """The weights' leaf table as `bench/weights.py` drew it before it took
+    the configuration's dict (from the program's `ModelConfig`), kept here
+    to show that the dict-driven table draws the same bits."""
+    import math
+
+    d, L, hd = cfg.d_model, cfg.n_layers, cfg.resolved_head_dim
+    H, KV, F, V = cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.vocab
+    blocks = {
+        "attn_norm": ((L, d), "gain"),
+        "wq": ((L, d, H * hd), 1 / math.sqrt(d)),
+        "wk": ((L, d, KV * hd), 1 / math.sqrt(d)),
+        "wv": ((L, d, KV * hd), 1 / math.sqrt(d)),
+        "wo": ((L, H * hd, d), 1 / math.sqrt(H * hd)),
+        "mlp_norm": ((L, d), "gain"),
+        "w_gate": ((L, d, F), 1 / math.sqrt(d)),
+        "w_up": ((L, d, F), 1 / math.sqrt(d)),
+        "w_down": ((L, F, d), 1 / math.sqrt(F)),
+    }
+    if cfg.qkv_bias:
+        blocks.update(bq=((L, H * hd), 0.02), bk=((L, KV * hd), 0.02),
+                      bv=((L, KV * hd), 0.02))
+    if cfg.qk_norm:
+        blocks.update(q_norm=((L, hd), "gain"), k_norm=((L, hd), "gain"))
+    tree = {"embed": ((V, d), 0.02), "final_norm": ((d,), "gain"),
+            "blocks": blocks}
+    if not cfg.tie_embeddings:
+        tree["lm_head"] = ((d, V), 0.02)
+    return tree
+
+
+@pytest.mark.parametrize("entry", CONFIGS, ids=[c["name"] for c in CONFIGS])
+def test_weights_draw_the_parents_bits(entry):
+    """`weights.make` on a configuration file's dict, at a small depth and
+    width, gives leaves bit-equal to the table drawn from `ModelConfig`."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench import harness, weights
+
+    cfg = dict(json.loads((ROOT / entry["file"]).read_text()),
+               n_layers=1, d_model=64, vocab=512)
+    seed = 2**31 + 77
+    leaves, treedef = jax.tree_util.tree_flatten(
+        _parent_table(harness.model_config(cfg)), is_leaf=lambda x: isinstance(x, tuple))
+
+    @jax.jit
+    def build(key):  # the parent's `make`, one jitted call
+        keys = jax.random.split(key, len(leaves))
+        return jax.tree_util.tree_unflatten(
+            treedef, [weights._draw(k, s, sc, jnp.bfloat16) for k, (s, sc) in zip(keys, leaves)])
+
+    parent = build(weights.key_for(seed, 0))
+    made = weights.make(cfg, seed)
+    assert jax.tree_util.tree_structure(made) == jax.tree_util.tree_structure(parent)
+    for a, b in zip(jax.tree_util.tree_leaves(made), jax.tree_util.tree_leaves(parent)):
+        assert a.dtype == b.dtype == jnp.bfloat16 and a.shape == b.shape
+        assert np.array_equal(np.asarray(a).view(np.uint16), np.asarray(b).view(np.uint16))
+
+
+ENGINES = [_mix(m)["engine"] for m in MIXES] + [
+    json.loads((DATA / "tiny-mix.json").read_text())["engine"]]
+
+
+@pytest.mark.parametrize("entry", CONFIGS, ids=[c["name"] for c in CONFIGS])
+@pytest.mark.parametrize("engine", ENGINES, ids=[f"batch{e['batch']}-log{e['log_slots']}"
+                                                 for e in ENGINES])
+def test_work_counts_equal_the_dense_formulas(entry, engine):
+    """The dispatching counts give, for a dense configuration, what the
+    readers computed inline before (decode_mfu: 2 per matmul weight plus
+    q.k and p.v; paged_attn_roofline: pages from below, then bytes and
+    FLOPs per step)."""
+    cfg = json.loads((ROOT / entry["file"]).read_text())
+    L, H, hd = cfg["n_layers"], cfg["n_heads"], cfg["head_dim"]
+    for context in (1, 17, 100, 1025, 2111):
+        assert flops.decode_token_flops(cfg, context) == (
+            2 * flops.matmul_params(cfg) + L * 2 * 2 * H * hd * context)
+    page = engine["page_size"]
+    for contexts in ([], [5], [100, 40, 101], [513, 1027, 2049, 530, 1040, 2060, 600, 16],
+                     list(range(120, 120 + 32 * 7, 7))):
+        pages = [flops.paged_pages_lower_bound(c, page, engine["log_slots"], engine["batch"])
+                 for c in contexts]
+        want = (flops.paged_attn_bytes(cfg, pages, page),
+                L * sum(4 * H * hd * p * page for p in pages))
+        assert flops.paged_attn_work(cfg, contexts, engine) == want
+
+
+def test_model_config_builds_nested_dataclasses():
+    from bench import harness
+    from repro.configs.base import MoEConfig
+
+    cfg = json.loads((DATA / "tiny-moe.json").read_text())
+    mc = harness.model_config(cfg)
+    assert mc.family == "moe" and isinstance(mc.moe, MoEConfig)
+    assert mc.moe == MoEConfig(num_experts=4, top_k=4, d_ff_expert=256, capacity_factor=1.0)
+    assert harness._tuples([1, [2, 3]]) == (1, (2, 3))
+    assert harness.model_config(json.loads((DATA / "tiny.json").read_text())).moe is None

@@ -11,7 +11,7 @@ import time
 
 import pytest
 
-from bench import check, control, harness
+from bench import control, flops, harness, refs, weights
 from bench.tests.conftest import ROOT
 
 CELL = "tiny.tiny-mix"
@@ -66,15 +66,51 @@ def test_a_token_altered_where_it_is_produced_fails_the_check(tiny_root, monkeyp
 
 def test_the_reference_ignores_padding_after_a_position(tiny_root):
     cell = harness.load_cell(tiny_root, CELL)
-    cfg = harness.model_config(cell.config)
     prompt, out = list(range(1, 40)), [5, 6, 7]
-    ref = check.reference_module(tiny_root, cell.config)
+    ref = refs.load(tiny_root, cell.config)
     from bench.weights import make
 
-    params = make(cfg, 9)
+    params = make(cell.config, 9, root=tiny_root)
     a = ref.served_logits(cell.config, params, prompt, out, bucket=64)
     b = ref.served_logits(cell.config, params, prompt, out, bucket=256)
     assert float(abs(a - b).max()) < 1e-4
+
+
+MOE = "tiny-moe.tiny-mix"
+
+
+def test_a_configuration_of_another_family_added_as_files_is_correct(tiny_moe_root):
+    """A mixture-of-experts configuration whose reference module (leaf
+    table, logits, FLOP count) is one of its added files."""
+    cell = harness.load_cell(tiny_moe_root, MOE)
+    assert harness.model_config(cell.config).moe.num_experts == 4
+    params = weights.make(cell.config, 3, root=tiny_moe_root)
+    assert params["blocks"]["we_gate"].shape == (2, 4, 256, 256)
+    assert "w_gate" not in params["blocks"]
+    res = harness.run(cell, 3, 1.0, False, time.perf_counter(), log=io.StringIO())
+    assert res["correct"] is True and res["failed"] == 0
+    assert res["check"]["max_logit_gap"]["limit"] == cell.limits["max_logit_gap"]["limit"]
+
+
+def test_the_float8_control_fails_the_moe_limit(tiny_moe_root):
+    cell = harness.load_cell(tiny_moe_root, MOE)
+    r = control.readings(cell, 4, 1.0, log=io.StringIO())
+    limit = cell.limits["max_logit_gap"]["limit"]
+    assert r["program"] <= limit < r["control"]
+
+
+def test_decode_mfu_uses_the_references_count(tiny_moe_root):
+    cell = harness.load_cell(tiny_moe_root, MOE)
+    ref = refs.load(tiny_moe_root, cell.config)
+    steps = [harness.Step(0.0, 1.0, [40, 70]), harness.Step(1.0, 2.0, [41])]
+    peaks = {"bf16_flops_per_s": 197e12}
+    rec = harness.RunRecord(cell, steps, (0.0, 2.0), {}, {"busy_ns": 50_000}, peaks)
+    own = sum(ref.decode_token_flops(cell.config, c) for c in (40, 70, 41))
+    dense = sum(flops.decode_token_flops(dict(cell.config, reference="dense_gqa"), c)
+                for c in (40, 70, 41))
+    assert own != dense
+    read = harness.load_metric(tiny_moe_root, "decode_mfu")
+    assert read(rec) == pytest.approx(100 * own / (50e-6 * 197e12))
 
 
 def _command(cwd, env_extra=None):
