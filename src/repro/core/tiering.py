@@ -98,18 +98,23 @@ def host_slot(kv_cfg: TieredKVConfig, req: int, logical: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def copy_pages(dst_k, dst_v, src_k, src_v, pairs: jax.Array):
-    """Copy pages src->dst pool. pairs: (F, 2) int32 (src_slot, dst_slot),
-    -1 rows ignored. Models the page-granular host<->HBM DMA."""
-    src, dst = pairs[:, 0], pairs[:, 1]
-    valid = (src >= 0) & (dst >= 0)
-    ssafe = jnp.maximum(src, 0)
-    dsafe = jnp.maximum(dst, 0)
-    cur_k = dst_k[:, dsafe]
-    cur_v = dst_v[:, dsafe]
-    new_k = jnp.where(valid[None, :, None, None, None], src_k[:, ssafe], cur_k)
-    new_v = jnp.where(valid[None, :, None, None, None], src_v[:, ssafe], cur_v)
-    return dst_k.at[:, dsafe].set(new_k), dst_v.at[:, dsafe].set(new_v)
+def promote_pages(hbm_k, hbm_v, host_k, host_v, pairs: jax.Array, n: jax.Array):
+    """Copy the first ``n`` rows of ``pairs`` ((F, 2) int32: host slot, HBM
+    slot) from the host-tier pools into the HBM pools, one page at a time;
+    rows from ``n`` on are padding and write nothing. Models the
+    page-granular host->HBM DMA. Jitted with the HBM pools donated, the
+    copy is done in place: it moves the pages it promotes and allocates no
+    pool."""
+
+    def copy_one(i, pools):
+        src, dst = pairs[i, 0], pairs[i, 1]
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                pool, jax.lax.dynamic_slice_in_dim(host, src, 1, axis=1), dst, axis=1)
+            for pool, host in zip(pools, (host_k, host_v))
+        )
+
+    return jax.lax.fori_loop(0, n, copy_one, (hbm_k, hbm_v))
 
 
 def write_prefill_pages(kv_cfg: TieredKVConfig, state, req: int, k, v):

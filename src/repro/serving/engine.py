@@ -13,7 +13,8 @@ Per decode step:
      promotion.
   2. promotion — up to ``promote_pages_per_step`` host->HBM page copies
      (the migration bandwidth budget); LRU eviction of non-scheduled
-     requests' pages under pool pressure.
+     requests' pages under pool pressure. The policy picks every page and
+     slot on the host; one device program then copies the step's pages.
   3. batch — up to ``batch`` READY requests, least-served-first (CFS).
   4. decode — one paged+logged token per scheduled request (device op).
   5. compaction — when the log can't hold another step, coalesce it into
@@ -36,11 +37,12 @@ While a profile is recorded, ``step`` marks each phase with a host span
 ``tiered.decode``, ``tiered.lru``) on the profiler's clock, which the
 device's events share; outside a profile the spans record nothing.
 
-The decode step and the compaction are compiled ahead of time when the
-engine is built (``compile_seconds`` records the set-up time of each, and
-``step_fn``/``compact_fn`` are the compiled programs, named
-``jit_step`` and ``jit_compact_log``); their kernel path follows the
-platform (``tiering.kernel_mode``).
+The decode step, the compaction and the promotion copy are compiled ahead
+of time when the engine is built (``compile_seconds`` records the set-up
+time of each, and ``step_fn``/``compact_fn``/``promote_fn`` are the
+compiled programs, named ``jit_step``, ``jit_compact_log`` and
+``jit_promote_pages``); their kernel path follows the platform
+(``tiering.kernel_mode``).
 """
 from __future__ import annotations
 
@@ -74,6 +76,7 @@ class ServeStats:
     decoded_tokens: int = 0
     parks: int = 0  # coordinated context switches
     promoted_pages: int = 0
+    promote_calls: int = 0  # promotion programs launched (one a step at most)
     evicted_pages: int = 0
     compactions: int = 0
     flushed_pages: int = 0
@@ -115,6 +118,15 @@ class TieredEngine:
         self.compact_fn = self._compile(
             "compact", jax.jit(compact_log, donate_argnums=0),
             self.state, flush, flush,
+        )
+        # "jit_promote_pages": one launch copies a step's promoted pages
+        # (padded to the budget) into the HBM pools, donated, so in place
+        st = self.state
+        self.promote_fn = self._compile(
+            "promote", jax.jit(tiering.promote_pages, donate_argnums=(0, 1)),
+            st["hbm_k"], st["hbm_v"], st["host_k"], st["host_v"],
+            jax.ShapeDtypeStruct((kv_cfg.promote_pages_per_step, 2), i32),
+            jax.ShapeDtypeStruct((), i32),
         )
         self.prefill_fn = jax.jit(spec.prefill)  # compiles once per length
         self.requests: Dict[int, Request] = {}
@@ -201,21 +213,33 @@ class TieredEngine:
                 return int(s)
         return None
 
-    def _promote(self, rid: int, logical: int, protect: set) -> bool:
+    def _promote(self, rid: int, logical: int, protect: set,
+                 copies: List[tuple]) -> bool:
+        """Map the page to a free (or evicted) HBM slot and queue its copy
+        in ``copies`` as (host slot, HBM slot); ``_copy_pages`` runs them."""
         slot = self._free_slot(protect)
         if slot is None:
             return False
-        pairs = jnp.asarray([[host_slot(self.kv, rid, logical), slot]], jnp.int32)
-        self.state["hbm_k"], self.state["hbm_v"] = tiering.copy_pages(
-            self.state["hbm_k"], self.state["hbm_v"],
-            self.state["host_k"], self.state["host_v"], pairs,
-        )
+        copies.append((host_slot(self.kv, rid, logical), slot))
         self.page_table[rid, logical] = slot
         self._table_changed = True
         self.hbm_owner[slot] = (rid, logical)
         self.lru[slot] = self._clock
         self.stats.promoted_pages += 1
         return True
+
+    def _copy_pages(self, copies: List[tuple]) -> None:
+        """One launch of the promotion program for the step's copies. Their
+        HBM slots are distinct (each promoted page joins the protect set, so
+        no slot is taken twice in a step), so the order is free."""
+        pairs = np.full((self.kv.promote_pages_per_step, 2), -1, np.int32)
+        pairs[: len(copies)] = copies
+        st = self.state
+        st["hbm_k"], st["hbm_v"] = self.promote_fn(
+            st["hbm_k"], st["hbm_v"], st["host_k"], st["host_v"],
+            jnp.asarray(pairs), np.int32(len(copies)),
+        )
+        self.stats.promote_calls += 1
 
     # ---- compaction ----
     def _compact(self) -> None:
@@ -272,14 +296,17 @@ class TieredEngine:
             budget = self.kv.promote_pages_per_step
             protect = {(r.rid, p) for r in ready for p in range(self._n_pages(r.rid))}
             parked.sort(key=lambda rm: len(rm[1]))
+            copies: List[tuple] = []
             for r, missing in parked:
                 self.stats.parks += 1
                 for p in missing:
                     if budget <= 0:
                         break
-                    if self._promote(r.rid, p, protect):
+                    if self._promote(r.rid, p, protect, copies):
                         protect.add((r.rid, p))
                         budget -= 1
+            if copies:
+                self._copy_pages(copies)
             if self._table_changed:
                 # a copy: on the CPU backend the device array may alias the
                 # host buffer, which later edits would then reach

@@ -124,3 +124,4 @@ def test_device_programs_carry_their_names(model):
     eng = engine(model, CASES["compaction"], n_new=2)
     assert eng.compact_fn.as_text().startswith("HloModule jit_compact_log")
     assert eng.step_fn.as_text().startswith("HloModule jit_step")
+    assert eng.promote_fn.as_text().startswith("HloModule jit_promote_pages")

@@ -124,6 +124,107 @@ def test_host_metadata_mirrors_device(model, case):
     assert eng.stats.steps > 0
 
 
+def _pools(rng, n_hbm=12, n_host=20):
+    """HBM K and V pools, then the host tier's: (L, pages, page_size, KV, hd)."""
+    return [jnp.asarray(rng.standard_normal((2, n, 4, 2, 8)), jnp.bfloat16)
+            for n in (n_hbm, n_hbm, n_host, n_host)]
+
+
+def _promote_fn(pools, budget):
+    hk, hv, sk, sv = pools
+    return jax.jit(tiering.promote_pages, donate_argnums=(0, 1)).lower(
+        hk, hv, sk, sv, jax.ShapeDtypeStruct((budget, 2), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("n", [0, 1, 5, 8])
+def test_promote_pages_equals_a_per_page_copy(n):
+    """The promotion program against a page-by-page numpy copy: random
+    distinct HBM slots, the first real row aimed at slot 0, padding rows
+    of -1 after the ``n`` real ones (n = 8 fills the budget); padding
+    writes nothing."""
+    budget = 8
+    rng = np.random.default_rng(n)
+    pools = _pools(rng)
+    want = [np.asarray(p).copy() for p in pools[:2]]
+    src = rng.choice(20, size=n, replace=False)
+    dst = np.concatenate([[0], rng.choice(np.arange(1, 12), size=budget, replace=False)])[:n]
+    for s, d in zip(src, dst):
+        for w, host in zip(want, pools[2:]):
+            w[:, d] = np.asarray(host)[:, s]
+    pairs = np.full((budget, 2), -1, np.int32)
+    pairs[:n] = np.stack([src, dst], axis=1)
+    got = _promote_fn(pools, budget)(*pools, jnp.asarray(pairs), np.int32(n))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+
+
+def test_promote_pages_donates_the_hbm_pools(model):
+    """The engine's promotion program takes the HBM pools in place: the
+    arrays it was given are deleted, the slow pools are only read."""
+    spec, params = model
+    eng = TieredEngine(spec, params, CASES["pool_pressure"])
+    st = eng.state
+    old = {k: st[k] for k in ("hbm_k", "hbm_v", "host_k", "host_v")}
+    pairs = np.full((eng.kv.promote_pages_per_step, 2), -1, np.int32)
+    pairs[0] = (3, 0)
+    new_k, new_v = eng.promote_fn(old["hbm_k"], old["hbm_v"], old["host_k"],
+                                  old["host_v"], jnp.asarray(pairs), np.int32(1))
+    assert old["hbm_k"].is_deleted() and old["hbm_v"].is_deleted()
+    assert not (old["host_k"].is_deleted() or old["host_v"].is_deleted())
+    np.testing.assert_array_equal(np.asarray(new_k[:, 0]), np.asarray(old["host_k"][:, 3]))
+    np.testing.assert_array_equal(np.asarray(new_v[:, 0]), np.asarray(old["host_v"][:, 3]))
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_one_promotion_launch_per_step_that_promotes(model, case):
+    """Each step launches the promotion program at most once, for exactly
+    the pages its host table newly maps, to distinct HBM slots; the parks,
+    promoted and evicted pages the stats count equal those worked out step
+    by step from the host table."""
+    spec, params = model
+    kv = CASES[case]
+    eng = TieredEngine(spec, params, kv)
+    for rid, p in {0: list(range(7, 27)), 1: list(range(40, 75)),
+                   2: list(range(5, 18))}.items():
+        eng.add_request(Request(rid=rid, prompt=p, max_new_tokens=20))
+    launches, real = [], eng.promote_fn
+
+    def spy(hk, hv, sk, sv, pairs, n):
+        launches.append((np.asarray(pairs), int(n)))
+        return real(hk, hv, sk, sv, pairs, n)
+
+    eng.promote_fn = spy
+    parks = promoted = evicted = promoting_steps = 0
+    while not all(r.done for r in eng.requests.values()):
+        # a compaction due now moves the watermarks before the residency check
+        compacts = eng.log_tail + kv.batch > kv.log_slots
+        marks = eng.lengths if compacts else eng.compacted
+        before = eng.page_table.copy()
+        parks += sum(
+            bool((before[r.rid, :-(-int(marks[r.rid]) // kv.page_size)] < 0).any())
+            for r in eng.requests.values() if not r.done)
+        n_launches = len(launches)
+        eng.step()
+        after = eng.page_table
+        new = np.argwhere((before < 0) & (after >= 0))
+        promoted += len(new)
+        evicted += int(((before >= 0) & (after < 0)).sum())
+        promoting_steps += bool(len(new))
+        assert len(launches) - n_launches == bool(len(new))
+        if len(new):
+            pairs, n = launches[-1]
+            assert n == len(new) and (pairs[n:] == -1).all()
+            assert len(set(pairs[:n, 1])) == n  # distinct HBM slots
+            assert sorted(map(tuple, pairs[:n].tolist())) == sorted(
+                (tiering.host_slot(kv, rid, lg), int(after[rid, lg])) for rid, lg in new)
+    st = eng.stats
+    assert st.promote_calls == len(launches) == promoting_steps > 0
+    assert (st.parks, st.promoted_pages, st.evicted_pages) == (parks, promoted, evicted)
+    if case == "serial_batch1":  # the case whose pool is too small to keep pages
+        assert evicted > 0
+
+
 def test_engine_pallas_path(model, monkeypatch):
     """Same equivalence through the Pallas kernels (interpret mode)."""
     monkeypatch.setattr(tiering, "kernel_mode", lambda: "interpret")
@@ -172,7 +273,7 @@ def test_serve_run_agrees_with_dense_baseline():
     assert all(r.done for r in eng.requests.values())
     st = eng.stats
     assert min(st.parks, st.promoted_pages, st.evicted_pages, st.compactions) > 0
-    assert set(eng.compile_seconds) == {"decode", "compact"}
+    assert set(eng.compile_seconds) == {"decode", "compact", "promote"}
     _, gaps = serve.baseline_serve(res["spec"], res["params"], res["prompts"],
                                    n_new, follow=res["outs"])
     assert sorted(gaps) == sorted(res["prompts"])

@@ -134,3 +134,18 @@ def test_served_programs_carry_kernels(one_chip, kv, monkeypatch):
     compact = jax.jit(functools.partial(tiering.compact_log, kv), donate_argnums=0)
     compiled = compact.lower(state, flush, flush).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_promotion_copies_in_place(one_chip, kv):
+    """The promotion program at full width: both HBM pools are donated and
+    aliased to its outputs, and its temporary memory holds no pool."""
+    pool = _sds((CFG.n_layers, kv.n_hbm_pages, kv.page_size, KV, HD), BF16, one_chip)
+    host = _sds((CFG.n_layers, kv.n_host_pages, kv.page_size, KV, HD), BF16, one_chip)
+    promote = jax.jit(tiering.promote_pages, donate_argnums=(0, 1))
+    compiled = promote.lower(
+        pool, pool, host, host, _sds((kv.promote_pages_per_step, 2), I32, one_chip),
+        _sds((), I32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * CFG.n_layers * kv.n_hbm_pages * kv.page_size * KV * HD
+    assert mem.alias_size_in_bytes == 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 8
