@@ -11,12 +11,13 @@ The paper's memory system, re-expressed for an LLM serving engine:
   adaptive migration     -> hot-page promotion into the HBM pool (engine
                             policy; LRU eviction under pressure)
   coordinated ctx switch -> the serving scheduler parks requests whose
-                            pages are not HBM-resident (predicted-slow,
-                            Algorithm-1-style estimate) and runs others
+                            pages are not HBM-resident and runs others
 
-All device state is a flat dict of fixed-shape arrays (jit/pjit friendly);
-policy (promotion targets, flush targets, scheduling) is host-side, exactly
-as the paper splits FTL policy (firmware) from the data path (hardware).
+The device holds data only, a flat dict of six arrays: each tier's K/V
+pools and the write log. Every index into them lives on the host and
+reaches a program as an argument; policy (promotion targets, flush targets,
+scheduling) is host-side too, as the paper splits FTL policy (firmware)
+from the data path (hardware).
 
 The kernels' path is chosen from the platform in one place,
 ``kernel_mode``: compiled Pallas kernels on a TPU, the jnp reference
@@ -25,10 +26,11 @@ everywhere else.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Tuple
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.kernels.log_compact.ops import log_compact
@@ -49,8 +51,6 @@ class TieredKVConfig:
     log_slots: int = 64
     batch: int = 4  # decode batch width (scheduled requests per step)
     promote_pages_per_step: int = 4  # host->HBM copy budget per step
-    fetch_page_us: float = 50.0  # per-page host->HBM latency estimate
-    park_threshold_us: float = 50.0  # Algorithm-1-style switch threshold
 
     @property
     def n_host_pages(self) -> int:
@@ -69,15 +69,8 @@ def init_state(
         "hbm_v": jnp.zeros(shape_pool, dtype),
         "host_k": jnp.zeros(shape_host, dtype),
         "host_v": jnp.zeros(shape_host, dtype),
-        "page_table": -jnp.ones((c.max_requests, c.max_pages_per_req), jnp.int32),
         "log_k": jnp.zeros((L, c.log_slots, KV, hd), dtype),
         "log_v": jnp.zeros((L, c.log_slots, KV, hd), dtype),
-        "log_meta": -jnp.ones((c.log_slots, 2), jnp.int32),
-        "log_tail": jnp.zeros((), jnp.int32),
-        "lengths": jnp.zeros((c.max_requests,), jnp.int32),
-        # compaction watermark: positions < compacted live in pages;
-        # positions >= compacted live in the write log (disjointness)
-        "compacted": jnp.zeros((c.max_requests,), jnp.int32),
     }
 
 
@@ -91,6 +84,30 @@ def kernel_mode() -> str:
 def host_slot(kv_cfg: TieredKVConfig, req: int, logical: int) -> int:
     """Backing-store slot for a request's logical page (direct-mapped)."""
     return req * kv_cfg.max_pages_per_req + logical
+
+
+# Columns of the decode step's batch rows (``batch_rows``); the page-table
+# row fills the columns from TABLE on.
+TOKEN, REQ, LENGTH, MARK, SLOT, TABLE = range(6)
+
+
+def batch_rows(kv_cfg: TieredKVConfig, tokens, req_ids: np.ndarray,
+               lengths: np.ndarray, compacted: np.ndarray,
+               page_table: np.ndarray, log_tail: int) -> np.ndarray:
+    """The decode step's input, one int32 row per batch slot: token, request
+    id, length (the position it writes), watermark, log slot (consecutive
+    from ``log_tail``) and page-table row. Padding rows (past ``req_ids``)
+    hold request -1, length and watermark 0 and request 0's table row."""
+    n = len(req_ids)
+    rows = np.zeros((kv_cfg.batch, TABLE + kv_cfg.max_pages_per_req), np.int32)
+    rows[:, REQ] = -1
+    rows[:n, TOKEN] = tokens
+    rows[:n, REQ] = req_ids
+    rows[:n, LENGTH] = lengths[req_ids]
+    rows[:n, MARK] = compacted[req_ids]
+    rows[:, SLOT] = log_tail + np.arange(kv_cfg.batch)
+    rows[:, TABLE:] = page_table[np.maximum(rows[:, REQ], 0)]
+    return rows
 
 
 # ---------------------------------------------------------------------------
@@ -120,7 +137,7 @@ def promote_pages(hbm_k, hbm_v, host_k, host_v, pairs: jax.Array, n: jax.Array):
 def write_prefill_pages(kv_cfg: TieredKVConfig, state, req: int, k, v):
     """Scatter a dense prefill cache (L, S, KV, hd) into the request's
     host-tier pages (the paper's initial placement: data starts in the
-    slow tier)."""
+    slow tier). Writes only the host-tier pools."""
     L, S, KV, hd = k.shape
     p = kv_cfg.page_size
     n = (S + p - 1) // p
@@ -138,17 +155,16 @@ def write_prefill_pages(kv_cfg: TieredKVConfig, state, req: int, k, v):
     state["host_v"] = jax.lax.dynamic_update_slice_in_dim(
         state["host_v"], pages_v.astype(state["host_v"].dtype), base, axis=1
     )
-    state["lengths"] = state["lengths"].at[req].set(S)
-    state["compacted"] = state["compacted"].at[req].set(S)
     return state
 
 
 def build_paged_decode_step(spec: ModelSpec, kv_cfg: TieredKVConfig):
     """Decode step over the tiered KV state for GQA decoder families
-    (dense/moe/vlm). Returns step(params, state, tokens, req_ids) ->
-    (next_tokens, updates), where ``updates`` holds only the state entries
-    the step writes (the log, its metadata and the lengths); the page pools
-    are read, never returned, so a step copies none of them.
+    (dense/moe/vlm). Returns step(params, state, rows, log_meta) ->
+    (next_tokens (batch, 1), log_k, log_v): ``rows`` from ``batch_rows``,
+    ``log_meta`` the host's log index ((log_slots, 2) int32 owner and
+    position, -1 where empty) with this step's rows in it. The HBM pools
+    are read, never returned, and no index is computed: the host owns them.
 
     The current token's K/V is appended to the write log (token-granular,
     no page read-modify-write — the paper's write path) and the attention
@@ -157,23 +173,14 @@ def build_paged_decode_step(spec: ModelSpec, kv_cfg: TieredKVConfig):
     cfg = spec.cfg
     mode = kernel_mode()
 
-    def step(params, state, tokens, req_ids):
-        B = tokens.shape[0]
-        safe_req = jnp.maximum(req_ids, 0)
-        lengths = jnp.where(req_ids >= 0, state["lengths"][safe_req], 0)  # (B,)
-        compacted = jnp.where(req_ids >= 0, state["compacted"][safe_req], 0)
-        page_table = state["page_table"][safe_req]  # (B, N)
+    def step(params, state, rows, log_meta):
+        B = rows.shape[0]
+        req_ids, lengths = rows[:, REQ], rows[:, LENGTH]
+        page_table = rows[:, TABLE:]  # (B, N)
+        tail = rows[0, SLOT]  # the rows' slots are consecutive
 
-        x = jnp.take(params["embed"], tokens, axis=0)  # (B, 1, d)
+        x = jnp.take(params["embed"], rows[:, TOKEN:TOKEN + 1], axis=0)  # (B, 1, d)
         positions = lengths[:, None]
-
-        tail = state["log_tail"]
-        meta_rows = jnp.stack(
-            [req_ids, jnp.where(req_ids >= 0, lengths, -1)], axis=-1
-        )
-        log_meta = jax.lax.dynamic_update_slice_in_dim(
-            state["log_meta"], meta_rows, tail, axis=0
-        )
 
         def body(x, xs):
             p_l, hbm_k_l, hbm_v_l, log_k_l, log_v_l = xs
@@ -191,7 +198,7 @@ def build_paged_decode_step(spec: ModelSpec, kv_cfg: TieredKVConfig):
             o = paged_decode_attention(
                 q[:, 0], hbm_k_l, hbm_v_l, page_table, lengths + 1,
                 log_k_l, log_v_l, log_meta,
-                page_lengths=compacted, req_ids=req_ids, mode=mode,
+                page_lengths=rows[:, MARK], req_ids=req_ids, mode=mode,
             )
             x2 = x + jnp.einsum("bh,hd->bd", o.reshape(B, -1), p_l["wo"])[:, None]
             h2 = rmsnorm(x2, p_l["mlp_norm"], cfg.norm_eps)
@@ -205,41 +212,27 @@ def build_paged_decode_step(spec: ModelSpec, kv_cfg: TieredKVConfig):
         )
         logits = unembed(cfg, params, x)[:, 0]
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-
-        updates = {
-            "log_k": log_k,
-            "log_v": log_v,
-            "log_meta": log_meta,
-            "log_tail": tail + B,
-            "lengths": state["lengths"].at[safe_req].add(
-                (req_ids >= 0).astype(jnp.int32)
-            ),
-        }
-        return next_tok, updates
+        return next_tok, log_k, log_v
 
     return step
 
 
-def compact_log(
-    kv_cfg: TieredKVConfig, state, flush_hbm: jax.Array, flush_host: jax.Array
-):
-    """Run log compaction into both pools and clear the log.
+def compact_log(hbm_k, hbm_v, host_k, host_v, log_k, log_v, log_meta,
+                flush_hbm: jax.Array, flush_host: jax.Array):
+    """Run log compaction into both pools; returns the four pools.
 
+    log_meta: the host's log index ((log_slots, 2) int32: owner request and
+    position, -1 where empty), which masks the log's stale rows.
     flush_hbm / flush_host: (F, 3) int32 (request, logical_page, pool_slot)
-    built by the engine from log_meta (unique dirty pages — the paper's
+    built by the engine from that index (unique dirty pages — the paper's
     first-level hash-table scan); rows with request -1 are padding."""
     mode = kernel_mode()
-    state = dict(state)
-    state["hbm_k"], state["hbm_v"] = log_compact(
-        state["hbm_k"], state["hbm_v"], state["log_k"], state["log_v"],
-        state["log_meta"], flush_hbm, mode=mode,
-    )
-    state["host_k"], state["host_v"] = log_compact(
-        state["host_k"], state["host_v"], state["log_k"], state["log_v"],
-        state["log_meta"], flush_host, mode=mode,
-    )
-    state["log_meta"] = -jnp.ones_like(state["log_meta"])
-    state["log_tail"] = jnp.zeros((), jnp.int32)
-    # everything logged so far is now in pages
-    state["compacted"] = state["lengths"]
-    return state
+    hbm_k, hbm_v = log_compact(
+        hbm_k, hbm_v, log_k, log_v, log_meta, flush_hbm, mode=mode)
+    # one flush's merged pages live at a time: unordered, XLA may run both
+    # merges before either scatter, tripling the temporary memory
+    hbm_k, hbm_v, log_k, log_v = jax.lax.optimization_barrier(
+        (hbm_k, hbm_v, log_k, log_v))
+    host_k, host_v = log_compact(
+        host_k, host_v, log_k, log_v, log_meta, flush_host, mode=mode)
+    return hbm_k, hbm_v, host_k, host_v

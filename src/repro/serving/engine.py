@@ -7,10 +7,8 @@ split.
 
 Per decode step:
   1. residency check — a request is READY iff all its KV pages are in the
-     HBM pool. Non-resident requests are PARKED (the coordinated context
-     switch: the predicted fetch delay, pages_missing * fetch_page_us,
-     always exceeds the park threshold) and their pages are queued for
-     promotion.
+     HBM pool. A request with any page not resident is PARKED (the
+     coordinated context switch) and its pages are queued for promotion.
   2. promotion — up to ``promote_pages_per_step`` host->HBM page copies
      (the migration bandwidth budget); LRU eviction of non-scheduled
      requests' pages under pool pressure. The policy picks every page and
@@ -20,12 +18,14 @@ Per decode step:
   5. compaction — when the log can't hold another step, coalesce it into
      resident pages (HBM) and parked pages (host tier), then swap-clear.
 
-The host owns the engine's metadata. The page mapping (``page_table``,
-``hbm_owner``, ``lru``) is written only here and sent to the device once a
-step, before the decode, when it changed. The compaction watermarks, the
-lengths, the log tail and the log's dirty pages are host copies of what the
-device programs write, kept at the point where the engine launches each
-write; every policy decision reads them and none reads the device.
+The host is the only owner of the engine's metadata: the page mapping
+(``page_table``, ``hbm_owner``, ``lru``), the lengths, the compaction
+watermarks (``compacted``), the log's tail and its index (``log_meta``:
+owner and position of each log slot). No device array holds any of them;
+the device state is the KV pools and the log. The decode step is handed
+the rows of its batch and the log index (two host->device transfers), the
+compaction the log index and its flush rows; every policy decision reads
+host state and none reads the device.
 
 Stats mirror the simulator's so the TPU runtime can be judged with the
 paper's own metrics (coalescing ratio, switch count, fetch traffic), and
@@ -104,24 +104,27 @@ class TieredEngine:
         # most one page), so one compaction program serves every flush
         flush = jax.ShapeDtypeStruct((kv_cfg.log_slots, 3), i32)
         self.compile_seconds: Dict[str, float] = {}
+        log_meta = jax.ShapeDtypeStruct((kv_cfg.log_slots, 2), i32)
         self.step_fn = self._compile(
             "decode", jax.jit(tiering.build_paged_decode_step(spec, kv_cfg)),
             params, self.state,
-            jax.ShapeDtypeStruct((B, 1), i32), jax.ShapeDtypeStruct((B,), i32),
+            jax.ShapeDtypeStruct((B, tiering.TABLE + kv_cfg.max_pages_per_req), i32),
+            log_meta,
         )
         # named for its program, "jit_compact_log" (a fresh function per
         # engine, as the step is, so each traces its own kernel path); the
-        # pools are donated: compaction rewrites them in place
-        def compact_log(state, flush_hbm, flush_host):
-            return tiering.compact_log(kv_cfg, state, flush_hbm, flush_host)
+        # four pools are donated: compaction rewrites them in place
+        def compact_log(*args):
+            return tiering.compact_log(*args)
 
+        st = self.state
         self.compact_fn = self._compile(
-            "compact", jax.jit(compact_log, donate_argnums=0),
-            self.state, flush, flush,
+            "compact", jax.jit(compact_log, donate_argnums=(0, 1, 2, 3)),
+            st["hbm_k"], st["hbm_v"], st["host_k"], st["host_v"],
+            st["log_k"], st["log_v"], log_meta, flush, flush,
         )
         # "jit_promote_pages": one launch copies a step's promoted pages
         # (padded to the budget) into the HBM pools, donated, so in place
-        st = self.state
         self.promote_fn = self._compile(
             "promote", jax.jit(tiering.promote_pages, donate_argnums=(0, 1)),
             st["hbm_k"], st["hbm_v"], st["host_k"], st["host_v"],
@@ -130,19 +133,18 @@ class TieredEngine:
         )
         self.prefill_fn = jax.jit(spec.prefill)  # compiles once per length
         self.requests: Dict[int, Request] = {}
-        # host-side metadata: the page mapping, owned here ...
+        # the metadata, owned here alone: the page mapping; the lengths and
+        # compaction watermarks (positions below the watermark live in
+        # pages, the rest in the log); the log's tail and its index, the
+        # (owner, position) of each slot, -1 from the tail on
         self.page_table = np.full(
             (kv_cfg.max_requests, kv_cfg.max_pages_per_req), -1, np.int32)
-        self._table_changed = False  # since it was last sent to the device
         self.hbm_owner: List[Optional[tuple]] = [None] * kv_cfg.n_hbm_pages
         self.lru: np.ndarray = np.zeros(kv_cfg.n_hbm_pages, np.int64)
-        # ... and copies of the device's watermarks, lengths and log tail,
-        # with the log's dirty pages {(rid, logical): tokens} since the last
-        # compaction (what log_meta holds)
-        self.compacted = np.zeros(kv_cfg.max_requests, np.int32)
         self.lengths = np.zeros(kv_cfg.max_requests, np.int32)
+        self.compacted = np.zeros(kv_cfg.max_requests, np.int32)
         self.log_tail = 0
-        self.dirty: Dict[tuple, int] = {}
+        self.log_meta = np.full((kv_cfg.log_slots, 2), -1, np.int32)
         self.stats = ServeStats()
         self._clock = 0
 
@@ -207,7 +209,6 @@ class TieredEngine:
             if self.hbm_owner[s] is not None and self.hbm_owner[s] not in protect:
                 rid, logical = self.hbm_owner[s]
                 self.page_table[rid, logical] = -1
-                self._table_changed = True
                 self.hbm_owner[s] = None
                 self.stats.evicted_pages += 1
                 return int(s)
@@ -222,7 +223,6 @@ class TieredEngine:
             return False
         copies.append((host_slot(self.kv, rid, logical), slot))
         self.page_table[rid, logical] = slot
-        self._table_changed = True
         self.hbm_owner[slot] = (rid, logical)
         self.lru[slot] = self._clock
         self.stats.promoted_pages += 1
@@ -243,29 +243,35 @@ class TieredEngine:
 
     # ---- compaction ----
     def _compact(self) -> None:
-        flush_hbm, flush_host = [], []
-        for (rid, logical), ntok in sorted(self.dirty.items()):
-            slot = int(self.page_table[rid, logical])
-            if slot >= 0:
-                flush_hbm.append([rid, logical, slot])
-            # ALWAYS flush to the host backing store (write-back tier);
-            # resident copies are updated in parallel (paper: cache updated
-            # alongside the log so flushes need no merge read)
-            flush_host.append([rid, logical, host_slot(self.kv, rid, logical)])
-            self.stats.flushed_pages += 1
-            self.stats.flushed_tokens += ntok
-        self.state = self.compact_fn(
-            self.state, self._flush_rows(flush_hbm), self._flush_rows(flush_host)
+        # the log's dirty pages, ascending (rid, logical), and their tokens
+        live = self.log_meta[self.log_meta[:, 0] >= 0]
+        pages, ntok = np.unique(
+            np.stack([live[:, 0], live[:, 1] // self.kv.page_size], axis=1),
+            axis=0, return_counts=True)
+        rid, logical = pages[:, 0], pages[:, 1]
+        slot = self.page_table[rid, logical]
+        # ALWAYS flush to the host backing store (write-back tier);
+        # resident copies are updated in parallel (paper: cache updated
+        # alongside the log so flushes need no merge read)
+        flush_hbm = np.stack([rid, logical, slot], axis=1)[slot >= 0]
+        flush_host = np.stack([rid, logical, host_slot(self.kv, rid, logical)], axis=1)
+        self.stats.flushed_pages += len(pages)
+        self.stats.flushed_tokens += int(ntok.sum())
+        st = self.state
+        st["hbm_k"], st["hbm_v"], st["host_k"], st["host_v"] = self.compact_fn(
+            st["hbm_k"], st["hbm_v"], st["host_k"], st["host_v"],
+            st["log_k"], st["log_v"], jnp.asarray(self.log_meta.copy()),
+            self._flush_rows(flush_hbm), self._flush_rows(flush_host),
         )
         self.compacted[:] = self.lengths
         self.log_tail = 0
-        self.dirty = {}
+        self.log_meta[:] = -1
         self.stats.compactions += 1
 
-    def _flush_rows(self, rows: List[List[int]]) -> jax.Array:
+    def _flush_rows(self, rows: np.ndarray) -> jax.Array:
         out = np.full((self.kv.log_slots, 3), -1, np.int32)
         out[:, 1] = 0
-        out[: len(rows)] = np.asarray(rows, np.int32).reshape(-1, 3)
+        out[: len(rows)] = rows
         return jnp.asarray(out)
 
     # ---- one engine step ----
@@ -307,11 +313,6 @@ class TieredEngine:
                         budget -= 1
             if copies:
                 self._copy_pages(copies)
-            if self._table_changed:
-                # a copy: on the CPU backend the device array may alias the
-                # host buffer, which later edits would then reach
-                self.state["page_table"] = jnp.asarray(self.page_table.copy())
-                self._table_changed = False
         # 3. schedule ready requests, least-served first (CFS)
         ready.sort(key=lambda r: r.served)
         batch = ready[: self.kv.batch]
@@ -319,23 +320,19 @@ class TieredEngine:
             return
         # 4. decode one token for the batch
         with TraceAnnotation("tiered.decode"):
-            B = self.kv.batch
-            req_ids = np.full((B,), -1, np.int32)
-            tokens = np.zeros((B, 1), np.int32)
-            for i, r in enumerate(batch):
-                req_ids[i] = r.rid
-                last = r.out[-1] if r.out else r.prompt[-1]
-                tokens[i, 0] = last
-            next_tok, updates = self.step_fn(
-                self.params, self.state, jnp.asarray(tokens), jnp.asarray(req_ids)
-            )
-            self.state.update(updates)
-            for r in batch:
-                n = int(self.lengths[r.rid])
-                key = (r.rid, n // self.kv.page_size)
-                self.dirty[key] = self.dirty.get(key, 0) + 1
-                self.lengths[r.rid] = n + 1
-            self.log_tail += B
+            rids = np.array([r.rid for r in batch], np.int32)
+            tail, n = self.log_tail, len(rids)
+            rows = tiering.batch_rows(self.kv, [r.out[-1] for r in batch], rids,
+                                      self.lengths, self.compacted, self.page_table, tail)
+            # the log index gains the real rows' (owner, position)
+            self.log_meta[tail:tail + n] = rows[:n, [tiering.REQ, tiering.LENGTH]]
+            # a copy: on the CPU backend the device array may alias the
+            # host buffer, which later edits would then reach
+            st = self.state
+            next_tok, st["log_k"], st["log_v"] = self.step_fn(
+                self.params, st, jnp.asarray(rows), jnp.asarray(self.log_meta.copy()))
+            self.lengths[rids] += 1
+            self.log_tail += self.kv.batch
             next_np = self._fetch(next_tok)
         for i, r in enumerate(batch):
             r.out.append(int(next_np[i, 0]))

@@ -8,7 +8,6 @@ on the CPU backend the guard lets implicit reads (``int(x)``,
 ``np.asarray(x)``) through.
 """
 import jax
-import numpy as np
 import pytest
 from jax.profiler import TraceAnnotation
 
@@ -44,21 +43,19 @@ def engine(model, kv, n_new=20):
 
 
 def reads_before_step(eng):
-    """The reads the next step makes, from the device's state before it: the
-    policy reads only host state, so the step reads its tokens if it
-    decodes, which it does iff a request is ready once a compaction due
+    """The reads the next step makes, from the engine's host state before
+    it: the policy reads only host state, so the step reads its tokens if
+    it decodes, which it does iff a request is ready once a compaction due
     now has moved the watermarks. Returns that count, whether the step
     compacts, the ready requests and the pages each active request needs."""
     kv = eng.kv
-    st = {k: np.asarray(eng.state[k])
-          for k in ("log_tail", "compacted", "lengths", "page_table")}
     active = [r for r in eng.requests.values() if not r.done]
-    compacts = int(st["log_tail"]) + kv.batch > kv.log_slots
+    compacts = eng.log_tail + kv.batch > kv.log_slots
     # compaction moves the watermark to the end
-    compacted = st["lengths"] if compacts else st["compacted"]
+    compacted = eng.lengths if compacts else eng.compacted
     pages = {r.rid: -(-int(compacted[r.rid]) // kv.page_size) for r in active}
     ready = {r.rid for r in active
-             if (st["page_table"][r.rid, :pages[r.rid]] >= 0).all()}
+             if (eng.page_table[r.rid, :pages[r.rid]] >= 0).all()}
     return int(bool(ready)), compacts, ready, pages
 
 

@@ -6,6 +6,7 @@ context switches, promotion/eviction = adaptive migration) and across log
 compactions — i.e. the paper's mechanisms change performance, never
 results.
 """
+import collections
 from pathlib import Path
 
 import jax
@@ -92,36 +93,50 @@ def test_engine_equals_dense_decode(model, case):
         assert stats.compactions > 0
 
 
-def assert_host_metadata_mirrors_device(eng):
-    st = {k: np.asarray(eng.state[k])
-          for k in ("page_table", "compacted", "lengths", "log_tail", "log_meta")}
-    np.testing.assert_array_equal(eng.page_table, st["page_table"])
-    np.testing.assert_array_equal(eng.compacted, st["compacted"])
-    np.testing.assert_array_equal(eng.lengths, st["lengths"])
-    assert eng.log_tail == int(st["log_tail"])
-    dirty = {}
-    for owner, pos in st["log_meta"]:
-        if owner >= 0 and pos >= 0:
-            key = (int(owner), int(pos) // eng.kv.page_size)
-            dirty[key] = dirty.get(key, 0) + 1
-    assert eng.dirty == dirty
-
-
 @pytest.mark.parametrize("case", list(CASES))
-def test_host_metadata_mirrors_device(model, case):
-    """The page table, watermarks, lengths, log tail and dirty pages the
-    policy reads on the host equal the device's after every step, so also
-    just before each compaction, which flushes the dirty pages."""
+def test_compaction_flushes_the_pages_logged_since_the_last(model, case):
+    """The host alone holds the engine's metadata: the device state is the
+    six data arrays. Each compaction flushes, from the host's log index,
+    the (request, page) of every token decoded since the last compaction,
+    in ascending order, with their counts: every page to the host tier, the
+    HBM-resident ones to their HBM slot too."""
     spec, params = model
-    eng = TieredEngine(spec, params, CASES[case])
+    kv = CASES[case]
+    eng = TieredEngine(spec, params, kv)
     for rid, p in {0: list(range(7, 27)), 1: list(range(40, 75)),
                    2: list(range(5, 18))}.items():
         eng.add_request(Request(rid=rid, prompt=p, max_new_tokens=20))
-    assert_host_metadata_mirrors_device(eng)
+    assert set(eng.state) == {"hbm_k", "hbm_v", "host_k", "host_v", "log_k", "log_v"}
+    flushes, real = [], eng.compact_fn
+
+    def spy(*args):
+        flushes.append((np.asarray(args[-2]), np.asarray(args[-1]), eng.page_table.copy()))
+        return real(*args)
+
+    eng.compact_fn = spy
+    logged = collections.Counter()  # (rid, page) -> tokens decoded into it
     while not all(r.done for r in eng.requests.values()):
+        served = {rid: len(r.out) for rid, r in eng.requests.items() if not r.done}
+        flushed = (eng.stats.flushed_pages, eng.stats.flushed_tokens)
+        n_flushes = len(flushes)
         eng.step()
-        assert_host_metadata_mirrors_device(eng)
-    assert eng.stats.steps > 0
+        if len(flushes) > n_flushes:  # compacted before this step's decode
+            hbm, host, table = flushes[-1]
+            pages = sorted(logged)
+            assert host[host[:, 0] >= 0].tolist() == [
+                [rid, lg, tiering.host_slot(kv, rid, lg)] for rid, lg in pages]
+            assert hbm[hbm[:, 0] >= 0].tolist() == [
+                [rid, lg, int(table[rid, lg])] for rid, lg in pages if table[rid, lg] >= 0]
+            assert (eng.stats.flushed_pages - flushed[0],
+                    eng.stats.flushed_tokens - flushed[1]) == (len(pages), sum(logged.values()))
+            logged.clear()
+        for rid, n in served.items():
+            r = eng.requests[rid]
+            if len(r.out) > n:  # decoded at position prompt + n - 1
+                logged[(rid, (len(r.prompt) + n - 1) // kv.page_size)] += 1
+    assert len(flushes) == eng.stats.compactions
+    if case == "compaction":
+        assert len(flushes) > 1
 
 
 def _pools(rng, n_hbm=12, n_host=20):

@@ -8,7 +8,6 @@ and log sizes are those of ``chip_smoke.py``. The topology is described
 inside a fixture, so importing this file loads no TPU library.
 """
 import dataclasses
-import functools
 import importlib.util
 from pathlib import Path
 
@@ -114,8 +113,10 @@ def test_log_compact_compiles(one_chip, kv, pool):
 
 
 def test_served_programs_carry_kernels(one_chip, kv, monkeypatch):
-    """One full-width layer of the engine's decode step, and its
-    compaction, on the compiled-kernel path the TPU backend selects."""
+    """One full-width layer of the engine's decode step, and its compaction
+    at full width, on the compiled-kernel path the TPU backend selects. The
+    compaction takes the four pools in place (donated and aliased), and its
+    temporary memory holds the merged pages of one flush, not of both."""
     monkeypatch.setattr(tiering, "kernel_mode", lambda: "compiled")
     spec = ModelSpec(dataclasses.replace(CFG, n_layers=1))
     params = _place(spec.abstract_params(), one_chip)
@@ -123,17 +124,26 @@ def test_served_programs_carry_kernels(one_chip, kv, monkeypatch):
         jax.eval_shape(lambda: tiering.init_state(kv, spec.cfg, dtype=BF16)),
         one_chip,
     )
-    B = kv.batch
+    log_meta = _sds((kv.log_slots, 2), I32, one_chip)
     step = jax.jit(tiering.build_paged_decode_step(spec, kv))
     compiled = step.lower(
-        params, state, _sds((B, 1), I32, one_chip), _sds((B,), I32, one_chip)
+        params, state,
+        _sds((kv.batch, tiering.TABLE + kv.max_pages_per_req), I32, one_chip), log_meta,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
+    state = _place(
+        jax.eval_shape(lambda: tiering.init_state(kv, CFG, dtype=BF16)), one_chip)
+    pools = [state[k] for k in ("hbm_k", "hbm_v", "host_k", "host_v")]
     flush = _sds((kv.log_slots, 3), I32, one_chip)
-    compact = jax.jit(functools.partial(tiering.compact_log, kv), donate_argnums=0)
-    compiled = compact.lower(state, flush, flush).compile()
+    compact = jax.jit(tiering.compact_log, donate_argnums=(0, 1, 2, 3))
+    compiled = compact.lower(
+        *pools, state["log_k"], state["log_v"], log_meta, flush, flush).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == sum(x.size * x.dtype.itemsize for x in pools)
+    merged = 2 * CFG.n_layers * kv.log_slots * kv.page_size * KV * HD  # K or V
+    assert mem.temp_size_in_bytes < 2 * merged
 
 
 def test_promotion_copies_in_place(one_chip, kv):
